@@ -30,8 +30,11 @@ from __future__ import annotations
 import argparse
 import time
 
+from repro.compile_cache import enable_compile_cache
+
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--seeds", type=int, default=3,
                     help="classifier seeds for Fig 4 (paper uses 20)")

@@ -6,9 +6,11 @@ and pick the switching classifier — the paper's model-selection step.
 import argparse
 
 from benchmarks.bench_classifiers import run as fig4_run
+from repro.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--seeds", type=int, default=1)
     ap.add_argument("--fast", action="store_true")

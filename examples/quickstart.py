@@ -20,9 +20,11 @@ from repro.core import (
 )
 from repro.core.layer import LIFParams
 from repro.core.runtime import run_network, run_reference
+from repro.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     print("=== 1. dataset: compile both paradigms over a character grid ===")
     ds = generate_dataset(
         source_grid=(50, 150, 300, 450),

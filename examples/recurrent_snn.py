@@ -14,11 +14,15 @@ back onto the hidden one:
    compile it with the fast-switching system — the classifier prejudges
    **per projection**, exactly as it prejudges chain layers.
 3. Execute the fused scan (Pallas kernels in interpret mode — the TPU
-   code path on CPU) and verify bit-identical spike trains against the
+   kernel bodies on CPU) and verify bit-identical spike trains against the
    brute-force unrolled numpy reference.
 4. Serve variable-length requests through the ServingEngine (no API
    change for graph models) with a partial-bucket age-out, and verify
    every reply equals its solo run.
+
+This is a CPU demo: ``interpret=True`` is hard-coded below, which runs
+the kernels through the Pallas interpreter on any backend.  It is not a
+chip path; ``chip_smoke.py`` is.
 
     PYTHONPATH=src python examples/recurrent_snn.py
 """
@@ -36,6 +40,7 @@ from repro.core import (
 from repro.core.layer import LIFParams, SNNNetwork
 from repro.core.runtime import network_executable, run_graph_reference
 from repro.serving import ServingEngine
+from repro.compile_cache import enable_compile_cache
 
 LIF = LIFParams(alpha=0.5, v_th=64.0)
 
@@ -58,6 +63,7 @@ def build_recurrent_net():
 
 
 def main():
+    enable_compile_cache()
     print("=== 1. train the prejudging classifier (small grid) ===")
     ds = generate_dataset(
         source_grid=(50, 150),

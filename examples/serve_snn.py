@@ -37,6 +37,7 @@ from repro.core import (
 from repro.core.layer import LIFParams
 from repro.core.runtime import network_executable
 from repro.serving import ServingEngine, ShedReply
+from repro.compile_cache import enable_compile_cache
 
 N_INPUT = 2048
 ALT_MODEL = "parallel-all"      # second tenant: all-parallel compilation
@@ -71,6 +72,7 @@ def poisson_traffic(rng, n_requests, base_steps, rate, arrival_hz):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--requests", type=int, default=64,
                     help="number of simulated inference requests")

@@ -11,9 +11,11 @@ import argparse
 import sys
 
 from repro.launch.train import main as train_main
+from repro.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="mamba2-130m")
     ap.add_argument("--full", action="store_true",

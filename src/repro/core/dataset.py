@@ -18,6 +18,8 @@ prejudging must work before any compilation.
 from __future__ import annotations
 
 import dataclasses
+import hashlib
+import inspect
 import os
 import time
 from typing import Optional
@@ -128,27 +130,45 @@ def generate_dataset(
     return ParadigmDataset(features, serial_pes, parallel_pes, labels)
 
 
-_DEFAULT_CACHE = os.path.join(
+_DATA_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(__file__)))),
-    "benchmarks", "data", "paradigm_dataset.npz",
+    "benchmarks", "data",
 )
+
+
+def dataset_cache_path(**kwargs) -> str:
+    """Cache file of the dataset ``generate_dataset(**kwargs)`` builds.
+
+    The file name carries a hash of every generator input (grids, seed,
+    hardware config, compiler flags; defaults filled in), so a file made
+    with other inputs, or by an older generator call, is never read in
+    its place.
+    """
+    bound = inspect.signature(generate_dataset).bind(**kwargs)
+    bound.apply_defaults()
+    inputs = {
+        k: tuple(v) if isinstance(v, (list, tuple)) else v
+        for k, v in bound.arguments.items() if k != "progress"
+    }
+    digest = hashlib.sha256(repr(sorted(inputs.items())).encode()).hexdigest()
+    return os.path.join(_DATA_DIR, f"paradigm_dataset_{digest[:16]}.npz")
 
 
 def load_or_generate(
     path: Optional[str] = None, *, progress: bool = True, extended: bool = False,
     **kwargs
 ) -> ParadigmDataset:
-    """Cached 16k dataset (generation takes ~1-2 min; cached under benchmarks/data).
+    """Cached dataset (generation takes ~1-3 min; cached under benchmarks/data).
 
     ``extended=True`` loads/generates the beyond-paper grid (large-source /
-    very-sparse / tiny-target cells included)."""
+    very-sparse / tiny-target cells included).  Without ``path`` the cache
+    file is :func:`dataset_cache_path` of the generator inputs."""
     if extended:
-        path = path or _DEFAULT_CACHE.replace(".npz", "_extended.npz")
         kwargs.setdefault("source_grid", EXT_SOURCE_GRID)
         kwargs.setdefault("target_grid", EXT_TARGET_GRID)
         kwargs.setdefault("density_grid", EXT_DENSITY_GRID)
         kwargs.setdefault("delay_grid", EXT_DELAY_GRID)
-    path = path or _DEFAULT_CACHE
+    path = path or dataset_cache_path(**kwargs)
     if os.path.exists(path):
         return ParadigmDataset.load(path)
     ds = generate_dataset(progress=progress, **kwargs)

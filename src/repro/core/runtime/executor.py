@@ -539,7 +539,7 @@ def _temporal_network(
             if forms[ei] == "temporal_sparse":
                 i_e = temporal_project_sparse(
                     *params[ei], x, delay_range=meta.delay_range,
-                    n_target=meta.n_target, interpret=interpret,
+                    n_target=meta.n_target,
                 )
             else:
                 i_e = temporal_project_dense(params[ei][0], x)
@@ -1051,6 +1051,40 @@ class NetworkExecutable:
         # alias the same array)
         slot = {p: k for k, p in enumerate(self.plan.update_order)}
         return tuple(outs[slot[tgt]] for tgt in self.plan.proj_tgt)
+
+    def lower(
+        self,
+        steps: int,
+        batch: int,
+        *,
+        path: str = "fused",
+        interpret: bool | None = None,
+        sharding=None,
+    ) -> jax.stages.Lowered:
+        """The program a ``path`` launch ("fused" | "vmap") of a
+        ``(steps, batch)`` masked train runs, lowered ahead of time.
+
+        It is the jit entry :meth:`run_device` / :meth:`run_batched` use,
+        so ``.compile().as_text()`` shows what a launch runs.  Arguments
+        are shapes only, placed on ``sharding`` when given: a device of a
+        described TPU topology compiles the program for that chip
+        without one attached.
+        """
+        forms = self.serial_forms(batch)
+        fn = self._get_fn(path, interpret, forms)
+
+        def shape(a):
+            return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
+
+        states = jax.eval_shape(
+            lambda: _init_graph_carry(self.plan, self.metas, batch)
+        )
+        return fn.lower(
+            jax.tree_util.tree_map(shape, self._params_for(forms)),
+            jax.tree_util.tree_map(shape, states),
+            shape(jax.ShapeDtypeStruct((steps, batch, self.n_input), jnp.float32)),
+            shape(jax.ShapeDtypeStruct((batch,), jnp.int32)),
+        )
 
     def run_device(
         self,

@@ -26,7 +26,7 @@ Three kernel *forms* implement that step:
 * :func:`serial_project_sparse` — the ELL gather form: synapses grouped
   into equal-length rows per (delay-slot, target) pair, each row
   *gathering* its sources' spike lanes (SpikeStream-style,
-  :mod:`repro.kernels.sparse_gather`).  Work ``O(B * R)`` like the event
+  :func:`ell_gather`, one XLA gather).  Work ``O(B * R)`` like the event
   form but with batch-contiguous reads instead of a scattered accumulate,
   so it scales linearly in batch; memory ``O(nnz)`` like the event form,
   so it is the only batch-friendly form sparse giants can run.
@@ -54,7 +54,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...kernels.lif_update import lif_update
-from ...kernels.sparse_gather import sparse_gather
 from ..layer import LIFParams, SNNLayer
 from ..serial_compiler import SerialProgram, compile_serial, unpack_rows
 from .reference import LIFState, init_state
@@ -291,6 +290,26 @@ def sparse_serial_operands(exe: SerialExecutable):
     return ell_val, ell_idx
 
 
+def ell_gather(
+    ell_val: jnp.ndarray,   # (R, L) f32 weights, 0 in padding lanes
+    ell_idx: jnp.ndarray,   # (R, L) i32 source indices, 0 in padding lanes
+    x: jnp.ndarray,         # (S, B) f32 presynaptic spikes
+) -> jnp.ndarray:
+    """``out[r, b] = sum_l ell_val[r, l] * x[ell_idx[r, l], b]``.  (R, B) f32.
+
+    One XLA gather plus a row-axis sum, on every backend: the TPU's
+    kernel compiler refuses an in-kernel vector gather, and a kernel that
+    kept the whole ``(S, B)`` train resident would not fit VMEM at 10^5
+    sources.  Padding lanes carry weight 0, so their gathered (row-0)
+    spikes never contribute.  All weights are int8-magnitude integers and
+    spikes are 0/1, so the f32 accumulation is exact and
+    order-independent — the property that keeps the sparse form
+    bit-identical to the event and dense forms.
+    """
+    gathered = x[ell_idx.reshape(-1)].reshape(*ell_idx.shape, x.shape[1])
+    return (gathered * ell_val[..., None]).sum(axis=1)
+
+
 @partial(
     jax.jit,
     static_argnames=("delay_range", "n_target", "interpret"),
@@ -309,14 +328,14 @@ def serial_project_sparse(
     """Sparse (ELL gather) synaptic-current step — same ring, same currents.
 
     Each ELL row gathers and accumulates one ``(delay, target)`` pair's
-    contribution for the whole batch (:mod:`repro.kernels.sparse_gather`);
+    contribution for the whole batch (:func:`ell_gather`);
     reshaping to ``(d_slots, B, T)`` and rolling by ``t`` lands delay-``d``
     sums in ring slot ``(t + d) % d_slots``, exactly where the event form's
     segment ids point.  Delay-0 rows are structurally empty (delays >= 1),
     so the current slot is read before anything lands in it.
     """
     d_slots = delay_range + 1
-    out = sparse_gather(ell_val, ell_idx, x_t.T, interpret=interpret)
+    out = ell_gather(ell_val, ell_idx, x_t.T)
     upd = out.reshape(d_slots, n_target, -1).transpose(0, 2, 1)  # (d,B,T)
     ring = ring + jnp.roll(upd, t, axis=0)
     i_t = ring[t % d_slots]

@@ -58,7 +58,7 @@ import jax
 import jax.numpy as jnp
 
 from ...kernels.lif_parallel_scan import lif_parallel_scan
-from ...kernels.sparse_gather import sparse_gather
+from .serial_runtime import ell_gather
 
 #: resolution modes, in preference order
 TEMPORAL_MODES = ("alpha0", "count", "iterative")
@@ -140,14 +140,13 @@ def temporal_project_sparse(
     *,
     delay_range: int,
     n_target: int,
-    interpret: bool | None = None,
 ) -> jnp.ndarray:
-    """Whole-train ELL projection: the per-step gather-accumulate kernel
+    """Whole-train ELL projection: the per-step gather-accumulate
     vmapped over time, then the same shift-and-sum as the dense form."""
     steps = x.shape[0]
     d_slots = delay_range + 1
     gat = jax.vmap(
-        lambda xt: sparse_gather(ell_val, ell_idx, xt.T, interpret=interpret)
+        lambda xt: ell_gather(ell_val, ell_idx, xt.T)
     )(x)                                               # (T, d_slots*N, B)
     y = gat.reshape(steps, d_slots, n_target, -1)
     y = jnp.transpose(y, (1, 0, 3, 2))                 # (d_slots, T, B, N)

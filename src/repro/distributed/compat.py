@@ -1,8 +1,8 @@
-"""JAX version-compatibility shims, defined once.
+"""The one ``shard_map`` entry point every call site imports.
 
-The shard_map shim used to be re-implemented at each call site; it now
-lives here alone and everything else imports it (``sharding`` re-exports
-it for backwards compatibility with older call sites).
+``sharding`` re-exports it for older call sites.  The installed JAX
+(``requirements.txt``) has ``jax.shard_map``; no fallback to
+``jax.experimental.shard_map`` is kept.
 """
 from __future__ import annotations
 
@@ -11,26 +11,10 @@ import jax
 
 def compat_shard_map(f, *, mesh, in_specs, out_specs, check_vma=True,
                      axis_names=None):
-    """``jax.shard_map`` across jax versions.
-
-    Newer jax exposes ``jax.shard_map(..., check_vma=, axis_names=)``;
-    older releases only have ``jax.experimental.shard_map.shard_map``
-    with ``check_rep=`` and an ``auto=`` set (the complement of the
-    manual ``axis_names``).  Callers write the new-API kwargs; this shim
-    translates when the old API is what's installed.
-    """
-    if hasattr(jax, "shard_map"):
-        kw = {"check_vma": check_vma}
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    kw = {"check_rep": check_vma}
+    """``jax.shard_map`` with the keyword set every call site writes."""
+    kw = {"check_vma": check_vma}
     if axis_names is not None:
-        kw["auto"] = frozenset(mesh.axis_names) - set(axis_names)
-    return _shard_map(
+        kw["axis_names"] = axis_names
+    return jax.shard_map(
         f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw
     )

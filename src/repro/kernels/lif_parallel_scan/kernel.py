@@ -12,10 +12,15 @@ grids iterate sequentially over the trailing axis, so a VMEM scratch row
 carries ``v[Q-1]`` from one chunk into the next and is reset whenever a
 new feature block starts (``chunk == 0``).
 
-Alpha powers are built by cumulative product, not ``alpha ** k`` — the
-chain of f32 multiplies is exact for alpha in {0, 1} and for dyadic
-alpha inside the f32 window, which is what keeps the kernel bit-identical
-to the sequential scan under the repo's integer-weight invariant.
+The alpha powers and ``L`` are built on the host, outside the kernel
+(Mosaic lowers neither ``cumprod`` nor a vector gather), by a sequential
+f32 cumulative product, not ``alpha ** k`` — the chain of f32 multiplies
+is exact for alpha in {0, 1} and for dyadic alpha inside the f32 window,
+which is what keeps the kernel bit-identical to the sequential scan
+under the repo's integer-weight invariant.  They enter the kernel as
+inputs with a constant index map, so they stay resident in VMEM across
+the grid.  The in-kernel f32 matmul runs at ``HIGHEST`` precision: the
+currents are integers that bf16 does not hold exactly.
 """
 from __future__ import annotations
 
@@ -23,37 +28,39 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _scan_kernel(c_ref, v_ref, carry_ref, *, alpha, q):
+def _scan_kernel(l_ref, apow_ref, c_ref, v_ref, carry_ref):
     chunk = pl.program_id(1)
 
     @pl.when(chunk == 0)
     def _reset():
         carry_ref[...] = jnp.zeros_like(carry_ref)
 
-    c = c_ref[...]                                     # (Q, Fb)
-    al = jnp.float32(alpha)
-    # pw[k] = alpha^k by exact cumulative product (pw[0] = 1).
-    pw = jnp.concatenate(
-        [jnp.ones((1,), jnp.float32),
-         jnp.cumprod(jnp.full((q - 1,), al, jnp.float32))]
-    )                                                  # (Q,)
-    row = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
-    diff = row - col
-    lmat = jnp.where(
-        diff >= 0, jnp.take(pw, jnp.maximum(diff, 0)), 0.0
-    )                                                  # (Q, Q) lower-tri
     v = jax.lax.dot_general(
-        lmat, c, (((1,), (0,)), ((), ())),
+        l_ref[...], c_ref[...], (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
-    )
-    v = v + (pw * al)[:, None] * carry_ref[...]        # alpha^(i+1) * carry
+    )                                                  # (Q, Fb)
+    v = v + apow_ref[...] * carry_ref[...]             # alpha^(i+1) * carry
     v_ref[...] = v
-    carry_ref[...] = v[q - 1 : q, :]
+    carry_ref[...] = v[-1:, :]
+
+
+def scan_operands(alpha: float, q: int):
+    """``(L, apow)``: the (Q, Q) lower-triangular ``L[i, j] = alpha^(i-j)``
+    and the (Q, 1) carry weights ``alpha^(i+1)``, from one sequential f32
+    cumulative product (``pw[0] = 1``)."""
+    pw = np.ones(q + 1, np.float32)
+    al = np.float32(alpha)
+    for k in range(1, q + 1):
+        pw[k] = pw[k - 1] * al
+    diff = np.arange(q)[:, None] - np.arange(q)[None, :]
+    lmat = np.where(diff >= 0, pw[np.maximum(diff, 0)], np.float32(0))
+    return lmat.astype(np.float32), pw[1:, None].copy()
 
 
 @functools.partial(jax.jit, static_argnames=("alpha", "chunk", "bf", "interpret"))
@@ -67,12 +74,17 @@ def affine_scan_pallas(
 ) -> jnp.ndarray:
     steps, feat = c.shape
     grid = (feat // bf, steps // chunk)
+    lmat, apow = scan_operands(alpha, chunk)
     return pl.pallas_call(
-        functools.partial(_scan_kernel, alpha=alpha, q=chunk),
+        _scan_kernel,
         grid=grid,
-        in_specs=[pl.BlockSpec((chunk, bf), lambda ff, tt: (tt, ff))],
+        in_specs=[
+            pl.BlockSpec((chunk, chunk), lambda ff, tt: (0, 0)),
+            pl.BlockSpec((chunk, 1), lambda ff, tt: (0, 0)),
+            pl.BlockSpec((chunk, bf), lambda ff, tt: (tt, ff)),
+        ],
         out_specs=pl.BlockSpec((chunk, bf), lambda ff, tt: (tt, ff)),
         out_shape=jax.ShapeDtypeStruct((steps, feat), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, bf), jnp.float32)],
         interpret=interpret,
-    )(c)
+    )(jnp.asarray(lmat), jnp.asarray(apow), c)

@@ -1,9 +1,9 @@
 """Public wrapper for the whole-train affine membrane scan."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
+from ..dispatch import use_reference
 from .kernel import affine_scan_pallas
 from .ref import affine_scan_ref
 
@@ -19,26 +19,22 @@ def lif_parallel_scan(
     """All-timesteps v[t] = alpha*v[t-1] + c[t] for c of shape (T, F).
 
     On TPU this runs the chunked Pallas kernel (MXU lower-triangular
-    matmul per chunk, VMEM carry between chunks).  In auto mode
-    (``interpret is None``) off-TPU the log-depth ``associative_scan``
-    reference runs instead — same arithmetic, bit-identical under the
-    integer-weight invariant.  Pass ``interpret=True`` to force the
-    Pallas kernel body through the interpreter (CI coverage of the TPU
-    path).
+    matmul per chunk, VMEM carry between chunks); ``interpret`` routes as
+    in :mod:`repro.kernels.dispatch` (auto off-TPU: the log-depth
+    ``associative_scan`` reference, bit-identical under the
+    integer-weight invariant).  Time and features shorter than their
+    block are one whole block; longer ones are zero-padded up to a block
+    multiple (trailing zero currents never reach the kept prefix).
     """
-    if interpret is None:
-        if jax.default_backend() != "tpu":
-            return affine_scan_ref(c, alpha=alpha)
-        interpret = False
+    if use_reference(interpret):
+        return affine_scan_ref(c, alpha=alpha)
     steps, feat = c.shape
-    ck = min(chunk, steps) if steps % min(chunk, steps) == 0 else steps
-    pt = (-steps) % ck
-    bf_eff = min(bf, feat) if feat % min(bf, feat) == 0 else feat
-    pf = (-feat) % bf_eff
+    ck, bf_eff = min(chunk, steps), min(bf, feat)
+    pt, pf = (-steps) % ck, (-feat) % bf_eff
     if pt or pf:
         c = jnp.pad(c, ((0, pt), (0, pf)))
     v = affine_scan_pallas(
-        c, alpha=alpha, chunk=ck, bf=bf_eff, interpret=interpret
+        c, alpha=alpha, chunk=ck, bf=bf_eff, interpret=bool(interpret)
     )
     return v[:steps, :feat]
 

@@ -1,9 +1,9 @@
 """jit'd public wrapper for the fused LIF update."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
+from ..dispatch import use_reference
 from .kernel import lif_update_pallas
 from .ref import lif_update_ref
 
@@ -21,27 +21,24 @@ def lif_update(
 ):
     """Fused V' = I + alpha*V - z*V_th; z' = V' >= V_th.  (N, B) f32 maps.
 
-    On TPU this runs the Pallas VPU kernel.  In auto mode (``interpret is
-    None``) off-TPU the jnp reference runs instead — the same elementwise
-    f32 expression, bit-identical, without interpreter overhead in the
-    per-timestep hot loop.  Pass ``interpret=True`` to force the Pallas
-    kernel body through the interpreter (CI coverage of the TPU path).
+    On TPU this runs the Pallas VPU kernel; ``interpret`` routes as in
+    :mod:`repro.kernels.dispatch` (auto off-TPU: the jnp reference, the
+    same elementwise f32 expression, bit-identical).  A dimension smaller
+    than its block is one whole block; a larger one is zero-padded up to
+    a block multiple, so the VMEM footprint of a grid step never grows
+    with the population.
     """
-    if interpret is None:
-        if jax.default_backend() != "tpu":
-            return lif_update_ref(i_t, v, z, alpha=alpha, v_th=v_th)
-        interpret = False
+    if use_reference(interpret):
+        return lif_update_ref(i_t, v, z, alpha=alpha, v_th=v_th)
     n, b = i_t.shape
-    bn_eff = min(bn, n) if n % min(bn, n) == 0 else n
-    pn = (-n) % bn_eff
-    bb_eff = min(bb, b) if b % min(bb, b) == 0 else b
-    pb = (-b) % bb_eff
+    bn_eff, bb_eff = min(bn, n), min(bb, b)
+    pn, pb = (-n) % bn_eff, (-b) % bb_eff
     if pn or pb:
         pad = lambda x: jnp.pad(x, ((0, pn), (0, pb)))
         i_t, v, z = pad(i_t), pad(v), pad(z)
     v_new, z_new = lif_update_pallas(
         i_t, v, z, alpha=alpha, v_th=v_th, bn=bn_eff, bb=bb_eff,
-        interpret=interpret,
+        interpret=bool(interpret),
     )
     return v_new[:n, :b], z_new[:n, :b]
 

@@ -1,9 +1,9 @@
-"""jit'd public wrapper: pads to MXU tiles, picks interpret mode off-TPU."""
+"""jit'd public wrapper: pads to MXU tiles, routes off-TPU to the reference."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
+from ..dispatch import use_reference
 from .kernel import spike_wdm_matmul_pallas
 from .ref import spike_wdm_matmul_ref
 
@@ -14,10 +14,6 @@ def _pad_to(x: jnp.ndarray, m0: int, m1: int) -> jnp.ndarray:
     if p0 or p1:
         x = jnp.pad(x, ((0, p0), (0, p1)))
     return x
-
-
-def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def spike_wdm_matmul(
@@ -31,17 +27,12 @@ def spike_wdm_matmul(
 ) -> jnp.ndarray:
     """int8 (M, K) @ int8 (K, N) -> int32 (M, N), auto-padded to tiles.
 
-    On TPU this runs the Pallas MXU kernel.  In auto mode (``interpret is
-    None``) off-TPU the exact jnp reference runs instead — every
-    accumulation is identical int32 math, and the reference is orders of
-    magnitude faster than interpreting the kernel grid block-by-block
-    inside a scan.  Pass ``interpret=True`` to force the Pallas kernel
-    body through the interpreter (CI coverage of the TPU code path).
+    On TPU this runs the Pallas MXU kernel; ``interpret`` routes as in
+    :mod:`repro.kernels.dispatch` (auto off-TPU: the jnp reference, the
+    same exact int32 accumulation).
     """
-    if interpret is None:
-        if not on_tpu():
-            return spike_wdm_matmul_ref(wdm, stacked)
-        interpret = False
+    if use_reference(interpret):
+        return spike_wdm_matmul_ref(wdm, stacked)
     m, k = wdm.shape
     _, n = stacked.shape
     if k == 0:
@@ -50,7 +41,7 @@ def spike_wdm_matmul(
     wdm_p = _pad_to(wdm, bm, bk_eff)
     stacked_p = _pad_to(stacked, bk_eff, bn)
     out = spike_wdm_matmul_pallas(
-        wdm_p, stacked_p, bm=bm, bn=bn, bk=bk_eff, interpret=interpret
+        wdm_p, stacked_p, bm=bm, bn=bn, bk=bk_eff, interpret=bool(interpret)
     )
     return out[:m, :n]
 

@@ -8,6 +8,7 @@ from __future__ import annotations
 import math
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -22,7 +23,9 @@ def make_production_mesh(*, multi_pod: bool = False):
             "(dry-run must set XLA_FLAGS=--xla_force_host_platform_device_count=512 "
             "before any jax import)"
         )
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return jax.make_mesh(
+        shape, axes, devices=devices[:n], axis_types=(AxisType.Auto,) * len(shape)
+    )
 
 
 def make_host_mesh(model_parallel: int = 1):
@@ -30,4 +33,5 @@ def make_host_mesh(model_parallel: int = 1):
     n = len(jax.devices())
     data = n // model_parallel
     return jax.make_mesh((data, model_parallel), ("data", "model"),
-                         devices=jax.devices()[: data * model_parallel])
+                         devices=jax.devices()[: data * model_parallel],
+                         axis_types=(AxisType.Auto,) * 2)

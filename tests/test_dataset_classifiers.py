@@ -2,7 +2,15 @@
 import numpy as np
 import pytest
 
-from repro.core import LABEL_PARALLEL, LABEL_SERIAL, generate_dataset
+from repro.core import (
+    LABEL_PARALLEL,
+    LABEL_SERIAL,
+    generate_dataset,
+    load_or_generate,
+)
+from repro.core import dataset as dataset_mod
+from repro.core.dataset import dataset_cache_path
+from repro.core.hw import SpiNNaker2Config
 from repro.core.classifiers import zoo, ZOO_NAMES
 
 
@@ -34,6 +42,35 @@ def test_dataset_deterministic():
     a, b = generate_dataset(**kw), generate_dataset(**kw)
     np.testing.assert_array_equal(a.serial_pes, b.serial_pes)
     np.testing.assert_array_equal(a.parallel_pes, b.parallel_pes)
+
+
+TINY = dict(source_grid=(100,), target_grid=(100,), density_grid=(0.5,),
+            delay_grid=(1, 4), seed=3)
+
+
+@pytest.mark.parametrize("change", [
+    dict(seed=4), dict(source_grid=(100, 200)), dict(delay_grid=(1, 8)),
+    dict(density_grid=(0.25,)), dict(hw=SpiNNaker2Config(dtcm_bytes=64 * 1024)),
+])
+def test_cache_path_keys_every_generator_input(change):
+    """A file made with other grids, seed or hardware config is never read
+    in place of this one: its name differs."""
+    base = dataset_cache_path(**TINY)
+    assert dataset_cache_path(**{**TINY, **change}) != base
+    assert dataset_cache_path(**TINY) == base          # deterministic
+    assert dataset_cache_path(**{**TINY, "source_grid": [100]}) == base
+
+
+def test_load_or_generate_caches_under_hashed_name(tmp_path, monkeypatch):
+    monkeypatch.setattr(dataset_mod, "_DATA_DIR", str(tmp_path))
+    stale = tmp_path / "paradigm_dataset.npz"            # old fixed name
+    stale.write_bytes(b"not a dataset")
+    ds = load_or_generate(progress=False, **TINY)
+    path = dataset_cache_path(**TINY)
+    assert path.startswith(str(tmp_path)) and path != str(stale)
+    again = load_or_generate(progress=False, **TINY)
+    np.testing.assert_array_equal(ds.serial_pes, again.serial_pes)
+    np.testing.assert_array_equal(ds.labels, again.labels)
 
 
 def test_paper_trends(mini_dataset):

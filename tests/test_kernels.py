@@ -131,3 +131,32 @@ class TestSSDChunk:
                                  jnp.asarray(c), jnp.asarray(la))
         np.testing.assert_allclose(np.asarray(y), y_seq, rtol=1e-4, atol=1e-4)
         np.testing.assert_allclose(np.asarray(state), s, rtol=1e-4, atol=1e-4)
+
+
+class TestDispatch:
+    """One rule routes every wrapper: the backend alone decides, and a
+    kernel that cannot compile fails the call instead of falling back."""
+
+    @pytest.mark.parametrize("interpret,want", [(None, True), (False, False),
+                                                (True, False)])
+    def test_use_reference_off_tpu(self, interpret, want):
+        from repro.kernels.dispatch import use_reference
+
+        assert use_reference(interpret) is want
+
+    @pytest.mark.parametrize("call", ["lif_update", "spike_wdm_matmul",
+                                      "lif_parallel_scan"])
+    def test_compiled_kernel_off_tpu_raises(self, call):
+        from repro.kernels.lif_parallel_scan import lif_parallel_scan
+
+        x = jnp.zeros((8, 1), jnp.float32)
+        run = {
+            "lif_update": lambda: lif_update(
+                x, x, x, alpha=0.5, v_th=64.0, interpret=False),
+            "spike_wdm_matmul": lambda: spike_wdm_matmul(
+                rand_wdm(4, 8), rand_spikes(8, 1), interpret=False),
+            "lif_parallel_scan": lambda: lif_parallel_scan(
+                x, alpha=0.5, interpret=False),
+        }[call]
+        with pytest.raises(ValueError, match="interpret mode"):
+            run()

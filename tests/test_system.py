@@ -85,3 +85,62 @@ class TestLaunchers:
             "--prompt-len", "8", "--gen", "4",
         ])
         assert out["tokens"].shape == (2, 4)
+
+
+class TestEntryPoints:
+    """What every entry point does before its first jit, and the chip
+    smoke's refusal to run anywhere but on a TPU."""
+
+    def test_compile_cache_defaults_to_fixed_repo_dir(self, monkeypatch):
+        import jax
+
+        from repro.compile_cache import DEFAULT_CACHE_DIR, enable_compile_cache
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        was = jax.config.jax_compilation_cache_dir
+        try:
+            assert enable_compile_cache() == str(DEFAULT_CACHE_DIR)
+            assert jax.config.jax_compilation_cache_dir == str(DEFAULT_CACHE_DIR)
+        finally:
+            jax.config.update("jax_compilation_cache_dir", was)
+        assert DEFAULT_CACHE_DIR.name == ".jax_cache"
+        assert (DEFAULT_CACHE_DIR.parent / "src" / "repro").is_dir()
+
+    def test_compile_cache_written_only_where_env_says(self, tmp_path):
+        """With ``JAX_COMPILATION_CACHE_DIR`` set, a compile lands there
+        and the helper sets no directory of its own."""
+        import os
+        import subprocess
+        import sys
+        from pathlib import Path
+
+        src = Path(__file__).resolve().parents[1] / "src"
+        probe = (
+            "import jax, jax.numpy as jnp\n"
+            "from repro.compile_cache import enable_compile_cache\n"
+            "print(enable_compile_cache())\n"
+            "print(jax.config.jax_compilation_cache_dir)\n"
+            "jax.jit(lambda x: jnp.sin(x) * 2)(jnp.ones(3)).block_until_ready()\n"
+        )
+        env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=str(src),
+                   JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+                   JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
+        out = subprocess.run([sys.executable, "-c", probe], env=env,
+                             capture_output=True, text=True, timeout=120,
+                             check=True).stdout.split()
+        assert out == [str(tmp_path), str(tmp_path)]
+        assert any(p.name.startswith("jit__lambda") for p in tmp_path.iterdir())
+
+    def test_chip_smoke_refuses_without_tpu(self, capsys):
+        import importlib.util
+        from pathlib import Path
+
+        import jax
+
+        assert jax.devices()[0].platform != "tpu"
+        path = Path(__file__).resolve().parents[1] / "chip_smoke.py"
+        spec = importlib.util.spec_from_file_location("chip_smoke", path)
+        smoke = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(smoke)
+        assert smoke.main() != 0
+        assert '"ok"' not in capsys.readouterr().out
