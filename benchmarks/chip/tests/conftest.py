@@ -1,0 +1,8 @@
+"""Puts the benchmark's harness and the program on the import path."""
+import sys
+from pathlib import Path
+
+CHIP = Path(__file__).resolve().parents[1]
+for p in (CHIP.parents[1] / "src", CHIP):
+    if str(p) not in sys.path:
+        sys.path.insert(0, str(p))
