@@ -38,13 +38,14 @@ from .temporal_runtime import (
     temporal_step,
 )
 
-from . import parallel_runtime as _par_rt
-from . import serial_runtime as _ser_rt
+from ... import tracing
 
 
 def lowering_counts() -> dict:
     """Total lower_serial / lower_parallel calls so far in this process."""
-    return {"serial": _ser_rt.LOWER_COUNT, "parallel": _par_rt.LOWER_COUNT}
+    c = tracing.counts()
+    return {"serial": c.get("lower.serial", 0),
+            "parallel": c.get("lower.parallel", 0)}
 
 
 def lowering_total() -> int:

@@ -73,6 +73,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ... import tracing
 from ...distributed import sharding as shardlib
 from ...kernels.lif_update import lif_update
 from ..cost_model import DEFAULT_SERIAL_BATCH_COST, SerialBatchCostModel
@@ -265,6 +266,18 @@ def _init_graph_carry(
         for s in plan.back_sources
     )
     return (tuple(proj), pop_v, pop_z, feedback)
+
+
+def _carry_arrays(plan: GraphPlan, metas: Tuple[LayerMeta, ...]) -> int:
+    """Arrays :func:`_init_graph_carry` makes: a ring per projection, a
+    membrane and a spike vector per population, a feedback vector per
+    back-edge source."""
+    return len(metas) + 2 * len(plan.update_order) + len(plan.back_sources)
+
+
+def _read_bytes(arrays) -> int:
+    """Bytes a host read of ``arrays`` moves, counted only for a sink."""
+    return sum(a.nbytes for a in arrays) if tracing.active() else 0
 
 
 def _carry_axes(plan: GraphPlan, metas: Tuple[LayerMeta, ...]):
@@ -1034,18 +1047,25 @@ class NetworkExecutable:
 
     def _launch(self, path, spikes, valid_steps, interpret, serial_form):
         valid_steps = self._check_shapes(spikes, valid_steps)
-        forms = self.serial_forms(spikes.shape[1], serial_form)
-        self._record_forms(
-            "vmap" if path == "vmap" else "fused", spikes.shape[1], forms
-        )
-        fn = self._get_fn(path, interpret, forms)
-        spikes, valid_steps = self._place_inputs(
-            jnp.asarray(spikes, jnp.float32), valid_steps
-        )
-        states = _init_graph_carry(self.plan, self.metas, spikes.shape[1])
-        outs, _final, self.last_check = fn(
-            self._params_for(forms), states, spikes, valid_steps
-        )
+        steps, batch = spikes.shape[:2]
+        with tracing.span("launch.prepare", path=path, batch=batch,
+                          steps=steps):
+            forms = self.serial_forms(batch, serial_form)
+            self._record_forms(
+                "vmap" if path == "vmap" else "fused", batch, forms
+            )
+            fn = self._get_fn(path, interpret, forms)
+            spikes, valid_steps = self._place_inputs(
+                jnp.asarray(spikes, jnp.float32), valid_steps
+            )
+            params = self._params_for(forms)
+        with tracing.span("launch.carry",
+                          arrays=_carry_arrays(self.plan, self.metas)):
+            states = _init_graph_carry(self.plan, self.metas, batch)
+        with tracing.span("launch.dispatch"):
+            outs, _final, self.last_check = fn(
+                params, states, spikes, valid_steps
+            )
         # per-population device trains -> the per-projection API view
         # (entry i = projection i's target population; fan-in entries
         # alias the same array)
@@ -1164,21 +1184,26 @@ class NetworkExecutable:
             return ()
         valid_steps = self._check_shapes(spikes, valid_steps)
         steps, batch = int(spikes.shape[0]), int(spikes.shape[1])
-        forms = self.temporal_forms(batch, steps, serial_form)
-        self._record_forms("temporal", batch, forms)
-        cap = int(max_iters) if max_iters else steps + 1
-        fn = self._get_fn("temporal", interpret, forms, max_iters=cap)
-        spikes, valid_steps = self._place_inputs(
-            jnp.asarray(spikes, jnp.float32), valid_steps
-        )
-        tp = self._temporal_structure()
-        states = (
-            _init_graph_carry(tp.sub_plan, self.metas, batch)
-            if tp.block else ()
-        )
-        outs, (aux, _fin), self.last_check = fn(
-            self._params_for(forms), states, spikes, valid_steps
-        )
+        with tracing.span("launch.prepare", path="temporal", batch=batch,
+                          steps=steps):
+            forms = self.temporal_forms(batch, steps, serial_form)
+            self._record_forms("temporal", batch, forms)
+            cap = int(max_iters) if max_iters else steps + 1
+            fn = self._get_fn("temporal", interpret, forms, max_iters=cap)
+            spikes, valid_steps = self._place_inputs(
+                jnp.asarray(spikes, jnp.float32), valid_steps
+            )
+            tp = self._temporal_structure()
+            params = self._params_for(forms)
+        states = ()
+        if tp.block:
+            with tracing.span("launch.carry",
+                              arrays=_carry_arrays(tp.sub_plan, self.metas)):
+                states = _init_graph_carry(tp.sub_plan, self.metas, batch)
+        with tracing.span("launch.dispatch"):
+            outs, (aux, _fin), self.last_check = fn(
+                params, states, spikes, valid_steps
+            )
         self._record_temporal(batch, steps, cap, aux)
         slot = {p: k for k, p in enumerate(self.plan.update_order)}
         return tuple(outs[slot[tgt]] for tgt in self.plan.proj_tgt)
@@ -1187,7 +1212,9 @@ class NetworkExecutable:
         if self.report is None:
             return
         tp = self._temporal_structure()
-        iters, resid = (np.asarray(a) for a in aux)
+        with tracing.span("launch.sync", what="passes", arrays=len(aux),
+                          bytes=_read_bytes(aux)):
+            iters, resid = (np.asarray(a) for a in aux)
         order = self.plan.update_order
         self.report.temporal[(batch, steps)] = TemporalReport(
             split=(len(tp.pre), len(tp.block), len(tp.post)),
@@ -1223,7 +1250,9 @@ class NetworkExecutable:
             serial_form=serial_form,
         )
         # single host sync, after the whole network finished on device
-        return [np.asarray(z) for z in outs]
+        with tracing.span("launch.sync", what="outputs", arrays=len(outs),
+                          bytes=_read_bytes(outs)):
+            return [np.asarray(z) for z in outs]
 
 
 class OutputValidationError(ValueError):

@@ -30,16 +30,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ... import tracing
 from ...kernels.lif_update import lif_update
 from ...kernels.spike_wdm_matmul import spike_wdm_matmul
 from ..layer import LIFParams, SNNLayer
 from ..parallel_compiler import OptFlags, ParallelProgram, compile_parallel
 from .reference import LIFState, init_state
-
-#: Total ``lower_parallel`` invocations (benchmarks assert executable caching
-#: keeps this at one per layer per report).
-LOWER_COUNT = 0
-
 
 @dataclasses.dataclass
 class ParallelExecutable:
@@ -61,8 +57,7 @@ def lower_parallel(
     program: ParallelProgram, lif: LIFParams | None = None
 ) -> ParallelExecutable:
     """Concatenate the optimized WDM slices into one (T x C) MXU operand."""
-    global LOWER_COUNT
-    LOWER_COUNT += 1
+    tracing.count("lower.parallel")
     mats, srcs, dls = [], [], []
     for sl in program.slices:
         n_cols = len(sl.col_sources)

@@ -53,15 +53,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ... import tracing
 from ...kernels.lif_update import lif_update
 from ..layer import LIFParams, SNNLayer
 from ..serial_compiler import SerialProgram, compile_serial, unpack_rows
 from .reference import LIFState, init_state
-
-#: Total ``lower_serial`` invocations (benchmarks assert executable caching
-#: keeps this at one per layer per report).
-LOWER_COUNT = 0
-
 
 @dataclasses.dataclass
 class SerialExecutable:
@@ -79,8 +75,7 @@ class SerialExecutable:
 
 def lower_serial(program: SerialProgram, lif: LIFParams | None = None) -> SerialExecutable:
     """Decode packed rows of every cell into flat gather arrays."""
-    global LOWER_COUNT
-    LOWER_COUNT += 1
+    tracing.count("lower.serial")
     ws, ds_, ss, ts = [], [], [], []
     for cell in program.cells:
         w, d, tgt_local = unpack_rows(cell.synaptic_rows)
