@@ -269,56 +269,6 @@ def run_batch_sweep(
     return sweep
 
 
-def run_donation(*, steps: int = 40, batch: int = 16) -> dict:
-    """Carry donation on vs off: the fused/batched jit entries donate the
-    scan state (membrane potentials, delay rings, spike-history rings,
-    feedback ring) so XLA updates them in place instead of
-    double-buffering.  Outputs are bit-identical either way (asserted);
-    the before/after steps/sec lands in ``BENCH_network.json`` under
-    ``"carry_donation"``.
-    """
-    print("\n# carry donation (donate_argnums on the fused/batched entries)")
-    lif = LIFParams(alpha=0.5, v_th=64.0)
-    sizes = [192, 160, 128, 96, 64]
-    net, report = _mixed_network(sizes, density=0.3, delay_range=4, lif=lif)
-    exe = network_executable(net, report)
-    rng = np.random.default_rng(0)
-    spikes = (rng.random((steps, batch, sizes[0])) < 0.2).astype(np.float32)
-    bsteps = steps * batch
-
-    result = {"steps": steps, "batch": batch}
-    outs = {}
-    for path, flag in (("fused", False), ("fused", True),
-                       ("vmap", False), ("vmap", True)):
-        exe.donate = flag
-        launch = exe.run_batched if path == "vmap" else exe.run_device
-        us = timeit(
-            lambda: jax.block_until_ready(launch(spikes)),
-            warmup=1, iters=5,
-        )
-        sps = bsteps / (us / 1e6)
-        key = f"{path}_{'donated' if flag else 'undonated'}"
-        result[f"{key}_us"] = us
-        result[f"{key}_batch_timesteps_per_s"] = sps
-        outs[(path, flag)] = [np.asarray(z) for z in launch(spikes)]
-        csv_row(f"network_{key}", us, f"batch_timesteps_per_s={sps:.0f}")
-    for path in ("fused", "vmap"):
-        for a, b in zip(outs[(path, False)], outs[(path, True)]):
-            np.testing.assert_array_equal(a, b)
-        result[f"{path}_donation_speedup"] = (
-            result[f"{path}_undonated_us"] / result[f"{path}_donated_us"]
-        )
-    exe.donate = True                    # leave the default on
-    _merge_json({"carry_donation": result})
-    print(
-        f"wrote {_JSON_PATH.name} carry_donation (fused "
-        f"{result['fused_donation_speedup']:.2f}x, vmap "
-        f"{result['vmap_donation_speedup']:.2f}x vs undonated)"
-    )
-    return result
-
-
 if __name__ == "__main__":
     run()
     run_batch_sweep()
-    run_donation()

@@ -69,7 +69,6 @@ def main() -> None:
     bench_kernels.run()
     bench_network.run()
     bench_network.run_batch_sweep()
-    bench_network.run_donation()
     bench_sparse.run(fast=args.fast)
     bench_temporal.run(fast=args.fast)
     bench_serving.run()

@@ -56,12 +56,13 @@ Batched and sharded execution (see ``docs/architecture.md``):
   :mod:`repro.distributed.sharding` (``snn_rules``: batch -> data,
   neurons -> model); on a single device it is the identity fallback.
 
-The scan carry (membrane potentials, delay rings, spike-history rings,
-feedback ring) is **donated** to the jitted entries
-(``donate_argnums``), so XLA updates the state buffers in place instead
-of double-buffering them; fresh zero states are cheap to rebuild per
-launch.  Set ``NetworkExecutable.donate = False`` to measure the
-difference (``benchmarks/bench_network.py`` records it).
+The program builds its own zero carry: the scan state (membrane
+potentials, delay rings, spike-history rings, feedback ring) is made
+inside the jitted launch program, where the zeros fuse into it and XLA
+allocates and updates the loop's buffers in place, so a launch does no
+device work on the host before its dispatch.  The ``carry.traced``
+counter of :mod:`repro.tracing` counts each time a zero state is traced:
+once per new jit entry, never on a warm launch.
 """
 from __future__ import annotations
 
@@ -236,8 +237,10 @@ def _init_graph_carry(
     plan: GraphPlan, metas: Tuple[LayerMeta, ...], batch: int
 ):
     """Fresh zero scan state: per-projection rings, per-population LIF
-    state, and the back-edge feedback ring.  Built OUTSIDE the jitted scan
-    so the jit entries can donate (and update in place) these buffers."""
+    state, and the back-edge feedback ring.  Called only while a jit
+    entry is traced, so the zeros are part of the launch program; the
+    ``carry.traced`` counter records each such trace."""
+    tracing.count("carry.traced")
     proj = []
     for meta in metas:
         if meta.paradigm == "serial":
@@ -268,24 +271,9 @@ def _init_graph_carry(
     return (tuple(proj), pop_v, pop_z, feedback)
 
 
-def _carry_arrays(plan: GraphPlan, metas: Tuple[LayerMeta, ...]) -> int:
-    """Arrays :func:`_init_graph_carry` makes: a ring per projection, a
-    membrane and a spike vector per population, a feedback vector per
-    back-edge source."""
-    return len(metas) + 2 * len(plan.update_order) + len(plan.back_sources)
-
-
 def _read_bytes(arrays) -> int:
     """Bytes a host read of ``arrays`` moves, counted only for a sink."""
     return sum(a.nbytes for a in arrays) if tracing.active() else 0
-
-
-def _carry_axes(plan: GraphPlan, metas: Tuple[LayerMeta, ...]):
-    """Batch-axis position of every carry leaf (the vmap in_axes pytree)."""
-    proj = tuple(1 if m.paradigm == "serial" else 2 for m in metas)
-    pop = tuple(0 for _ in plan.update_order)
-    fb = tuple(0 for _ in plan.back_sources)
-    return (proj, pop, pop, fb)
 
 
 def _scan_network(
@@ -294,7 +282,6 @@ def _scan_network(
     forms: Tuple[str, ...],       # per proj: "event" | "sparse" | "dense" | "-"
     interpret: bool | None,
     params: List[Tuple[jnp.ndarray, ...]],
-    states,                       # _init_graph_carry output (donated)
     spikes: jnp.ndarray,          # (T, B, n_input) f32
     valid_steps: jnp.ndarray | None = None,   # (B,) i32 true length per request
 ):
@@ -381,14 +368,11 @@ def _scan_network(
         )
         return carry, outs
 
-    init = (jnp.int32(0),) + states
-    final, outs = jax.lax.scan(step, init, spikes)
+    init = (jnp.int32(0),) + _init_graph_carry(plan, metas, spikes.shape[1])
+    _, outs = jax.lax.scan(step, init, spikes)
     if live is not None:
         outs = tuple(z * live for z in outs)
-    # the final carry is returned (and dropped by the launch wrappers) so
-    # the donated input state buffers can alias it — the scan then runs
-    # in place in the donated membrane / ring buffers
-    return outs, final[1:]
+    return outs
 
 
 def _batched_scan(
@@ -397,42 +381,29 @@ def _batched_scan(
     forms: Tuple[str, ...],
     interpret: bool | None,
     params: List[Tuple[jnp.ndarray, ...]],
-    states,                       # full-batch carry, vmapped per lane
     spikes: jnp.ndarray,          # (T, B, n_input) f32
     valid_steps: jnp.ndarray | None = None,   # (B,) i32
 ):
     """``jax.vmap`` of the single-request scan over the request axis.
 
-    Each request runs its own width-1 scan; vmap batches them.  The
-    full-batch carry is split per lane along each leaf's batch axis
-    (``_carry_axes``) and rebuilt at width 1 inside the lane, so the
-    per-lane ``valid_steps`` mask and the donated-state layout are
-    preserved — lanes with 0 valid steps (padded slots) emit exact zeros
-    just like the fused path.
+    Each request runs its own width-1 scan, from the width-1 zero state
+    that scan builds inside its lane; vmap batches them.  The per-lane
+    ``valid_steps`` mask is preserved — lanes with 0 valid steps (padded
+    slots) emit exact zeros just like the fused path.
     """
-    axes = _carry_axes(plan, metas)
 
-    def one(st, sp, vs):          # sp (T, n_in), vs () i32 or None
-        st = jax.tree_util.tree_map(
-            lambda a, ax: jnp.expand_dims(a, ax), st, axes
-        )
-        outs, fin = _scan_network(
-            plan, metas, forms, interpret, params, st, sp[:, None, :],
+    def one(sp, vs):              # sp (T, n_in), vs () i32 or None
+        outs = _scan_network(
+            plan, metas, forms, interpret, params, sp[:, None, :],
             None if vs is None else vs[None],
         )
-        fin = jax.tree_util.tree_map(
-            lambda a, ax: jnp.squeeze(a, ax), fin, axes
-        )
-        return tuple(z[:, 0] for z in outs), fin
+        return tuple(z[:, 0] for z in outs)
 
     if valid_steps is None:
-        return jax.vmap(
-            lambda st, sp: one(st, sp, None),
-            in_axes=(axes, 1), out_axes=(1, axes),
-        )(states, spikes)
-    return jax.vmap(one, in_axes=(axes, 1, 0), out_axes=(1, axes))(
-        states, spikes, valid_steps
-    )
+        return jax.vmap(lambda sp: one(sp, None), in_axes=1, out_axes=1)(
+            spikes
+        )
+    return jax.vmap(one, in_axes=(1, 0), out_axes=1)(spikes, valid_steps)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -516,7 +487,6 @@ def _temporal_network(
     tplan: TemporalPlan,
     max_iters: int,
     params: List[Tuple[jnp.ndarray, ...]],
-    states,                      # block carry (donated); () when no block
     spikes: jnp.ndarray,         # (T, B, n_input) f32
     valid_steps: jnp.ndarray | None = None,
 ):
@@ -566,13 +536,11 @@ def _temporal_network(
 
     for p in tplan.pre:
         whole_train(p)
-    fin = states
     if tplan.block:
         aug = [pop_out[s] for s in tplan.ext_sources]
         aug = aug[0] if len(aug) == 1 else jnp.concatenate(aug, axis=2)
-        block_outs, fin = _scan_network(
-            tplan.sub_plan, metas, forms, interpret, params, states, aug,
-            None,
+        block_outs = _scan_network(
+            tplan.sub_plan, metas, forms, interpret, params, aug, None,
         )
         for p, z in zip(tplan.block, block_outs):
             pop_out[p] = z
@@ -591,9 +559,7 @@ def _temporal_network(
     aux_resid = jnp.stack(
         [aux.get(p, (zero, zero))[1] for p in plan.update_order]
     )
-    # the block's final carry is returned (and dropped by run_temporal)
-    # so the donated state buffers can alias it, as on the fused path
-    return outs, ((aux_iters, aux_resid), fin)
+    return outs, (aux_iters, aux_resid)
 
 
 def _param_axes(meta: LayerMeta, form: str) -> Tuple[Tuple, ...]:
@@ -637,10 +603,7 @@ class NetworkExecutable:
         self.report = report
         #: Crossover model deciding event vs dense serial form per batch.
         self.cost_model = cost_model or DEFAULT_SERIAL_BATCH_COST
-        #: Donate the scan carry to the jitted entries so membrane / ring
-        #: buffers update in place (fresh zeros are rebuilt per launch).
-        self.donate = True
-        self._fns = {}       # (path, interpret, forms, donate) -> jitted scan
+        self._fns = {}       # (path, interpret, forms, max_iters) -> jit fn
         self._dense = {}     # layer index -> (d_slots, S, T) dense operand
         self._sparse = {}    # layer index -> (ell_val, ell_idx) ELL operands
         self._temporal = {}  # layer index -> whole-train dense operand
@@ -1009,7 +972,7 @@ class NetworkExecutable:
         self, path: str, interpret, forms: Tuple[str, ...],
         max_iters: int | None = None,
     ):
-        key = (path, interpret, forms, self.donate, max_iters)
+        key = (path, interpret, forms, max_iters)
         fn = self._fns.get(key)
         if fn is None:
             if path == "temporal":
@@ -1023,8 +986,10 @@ class NetworkExecutable:
                     scan, self.plan, self.metas, forms, interpret
                 )
 
-            def checked(params, states, spikes, valid_steps):
-                outs, final = inner(params, states, spikes, valid_steps)
+            def checked(params, spikes, valid_steps):
+                outs, aux = inner(params, spikes, valid_steps), ()
+                if path == "temporal":   # + the fixed point's pass counts
+                    outs, aux = outs
                 # in-graph output self-check: every spike entry must be
                 # exactly 0.0 or 1.0 (subsumes finiteness — NaN and Inf
                 # equal neither), reduced to one scalar the launch
@@ -1034,14 +999,9 @@ class NetworkExecutable:
                     ok = jnp.logical_and(
                         ok, jnp.all((z == 0.0) | (z == 1.0))
                     )
-                return outs, final, ok
+                return outs, aux, ok
 
-            fn = jax.jit(
-                checked,
-                # donate the carry (arg 1: states) so membrane / ring
-                # buffers update in place
-                donate_argnums=(1,) if self.donate else (),
-            )
+            fn = jax.jit(checked)
             self._fns[key] = fn
         return fn
 
@@ -1059,13 +1019,8 @@ class NetworkExecutable:
                 jnp.asarray(spikes, jnp.float32), valid_steps
             )
             params = self._params_for(forms)
-        with tracing.span("launch.carry",
-                          arrays=_carry_arrays(self.plan, self.metas)):
-            states = _init_graph_carry(self.plan, self.metas, batch)
         with tracing.span("launch.dispatch"):
-            outs, _final, self.last_check = fn(
-                params, states, spikes, valid_steps
-            )
+            outs, _, self.last_check = fn(params, spikes, valid_steps)
         # per-population device trains -> the per-projection API view
         # (entry i = projection i's target population; fan-in entries
         # alias the same array)
@@ -1096,12 +1051,8 @@ class NetworkExecutable:
         def shape(a):
             return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
 
-        states = jax.eval_shape(
-            lambda: _init_graph_carry(self.plan, self.metas, batch)
-        )
         return fn.lower(
             jax.tree_util.tree_map(shape, self._params_for(forms)),
-            jax.tree_util.tree_map(shape, states),
             shape(jax.ShapeDtypeStruct((steps, batch, self.n_input), jnp.float32)),
             shape(jax.ShapeDtypeStruct((batch,), jnp.int32)),
         )
@@ -1193,17 +1144,9 @@ class NetworkExecutable:
             spikes, valid_steps = self._place_inputs(
                 jnp.asarray(spikes, jnp.float32), valid_steps
             )
-            tp = self._temporal_structure()
             params = self._params_for(forms)
-        states = ()
-        if tp.block:
-            with tracing.span("launch.carry",
-                              arrays=_carry_arrays(tp.sub_plan, self.metas)):
-                states = _init_graph_carry(tp.sub_plan, self.metas, batch)
         with tracing.span("launch.dispatch"):
-            outs, (aux, _fin), self.last_check = fn(
-                params, states, spikes, valid_steps
-            )
+            outs, aux, self.last_check = fn(params, spikes, valid_steps)
         self._record_temporal(batch, steps, cap, aux)
         slot = {p: k for k, p in enumerate(self.plan.update_order)}
         return tuple(outs[slot[tgt]] for tgt in self.plan.proj_tgt)

@@ -3,11 +3,12 @@
 A launch marks its phases with ``tracing.span``; with no sink they cost a
 global read and record nothing, with one installed they reach it in order,
 each phase closed before the next opens, with the attributes an operator
-reads (arrays made or read, bytes).
+reads (arrays read, bytes).  The scan's zero carry is traced into the
+launch program (``carry.traced`` counts each trace), so a warm launch
+builds none on the host.
 """
 import contextlib
 
-import jax
 import numpy as np
 import pytest
 
@@ -19,7 +20,6 @@ from repro.core.layer import (
 from repro.core.runtime import (
     lower_parallel, lower_serial, lowering_counts, network_executable,
 )
-from repro.core.runtime.executor import _carry_arrays, _init_graph_carry
 from repro.core.switching import CompileReport
 
 LIF = LIFParams(alpha=0.5, v_th=64.0)
@@ -94,11 +94,11 @@ def test_scan_launch_records_its_phases_in_order():
     exe, spikes = _net(CHAIN)
     rec = _record(exe, spikes)
     names = [n for n, _, _ in rec.opened]
-    assert names == ["outer", "launch.prepare", "launch.carry",
-                     "launch.dispatch", "launch.sync"]
+    assert names == ["outer", "launch.prepare", "launch.dispatch",
+                     "launch.sync"]
     # every phase sits directly in the caller's span and closes before
     # the next opens
-    assert [d for _, _, d in rec.opened] == [0, 1, 1, 1, 1]
+    assert [d for _, _, d in rec.opened] == [0, 1, 1, 1]
     assert rec.closed == names[1:] + ["outer"]
     attrs = {n: a for n, a, _ in rec.opened}
     assert attrs["launch.prepare"] == {"path": "fused", "batch": BATCH,
@@ -110,12 +110,24 @@ def test_scan_launch_records_its_phases_in_order():
     assert sync["bytes"] == sum(z.nbytes for z in outs)
 
 
-@pytest.mark.parametrize("shape", [CHAIN, LOOP], ids=["chain", "loop"])
-def test_carry_arrays_count_the_leaves_made(shape):
-    exe, _ = _net(shape)
-    leaves = jax.tree_util.tree_leaves(
-        _init_graph_carry(exe.plan, exe.metas, BATCH))
-    assert _carry_arrays(exe.plan, exe.metas) == len(leaves)
+@pytest.mark.parametrize("shape, kw", [
+    (CHAIN, {}), (CHAIN, {"batched": True}),
+    (LOOP, {}), (LOOP, {"temporal": True}),
+], ids=["chain-fused", "chain-vmap", "loop-fused", "loop-temporal"])
+def test_warm_launch_traces_no_carry(shape, kw):
+    """The zero carry is traced once, into the new jit entry; a warm
+    launch builds none, on the host or anywhere."""
+    exe, spikes = _net(shape)
+    before = tracing.counts().get("carry.traced", 0)
+    exe.run(spikes, **kw)
+    warm = tracing.counts().get("carry.traced", 0)
+    assert warm > before
+    rec = Recorder()
+    with tracing.installed(rec):
+        exe.run(spikes, **kw)
+    assert tracing.counts().get("carry.traced", 0) == warm
+    assert "launch.carry" not in [n for n, _, _ in rec.opened]
+    assert "launch.dispatch" in [n for n, _, _ in rec.opened]
 
 
 def test_vmap_launch_names_its_path():
@@ -141,13 +153,16 @@ def test_temporal_launch_reads_passes_then_outputs():
     assert rec.opened[1][1]["path"] == "temporal"
 
 
-def test_temporal_launch_with_a_back_edge_builds_its_carry():
+def test_temporal_launch_with_a_back_edge_matches_the_scans():
+    """The back-edge block's zero state, made inside the temporal
+    program, gives the fused and vmapped scans' bits."""
     exe, spikes = _net(LOOP)
-    rec = _record(exe, spikes, temporal=True)
-    carry = [a for n, a, _ in rec.opened if n == "launch.carry"]
-    tp = exe._temporal_structure()
-    assert tp.block
-    assert carry == [{"arrays": _carry_arrays(tp.sub_plan, exe.metas)}]
+    assert exe._temporal_structure().block
+    got = exe.run(spikes, temporal=True)
+    for base in (exe.run(spikes), exe.run(spikes, batched=True)):
+        for a, b in zip(got, base):
+            np.testing.assert_array_equal(a, b)
+    assert sum(float(z.sum()) for z in got) > 0
 
 
 def test_installed_restores_the_previous_sink_after_an_exception():
