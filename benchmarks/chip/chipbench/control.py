@@ -1,4 +1,5 @@
-"""The control: the plain reference, in bfloat16, put in the program's place.
+"""The control: the configuration's plain reference, in bfloat16, put in
+the program's place.
 
 The configurations state float32 for the membrane update, over synaptic
 sums that are exact integers.  The nearest precision below float32 is
@@ -10,12 +11,10 @@ from __future__ import annotations
 
 import ml_dtypes
 
-from . import reference
-
 
 def bf16_answers(driver) -> None:
     """Replace every answer the driver will check with the bf16 reference's."""
-    spec = driver.h.spec
+    spec, reference = driver.h.spec, driver.h.reference
     driver.replace_answers(
         lambda x: reference.simulate(spec, x, dtype=ml_dtypes.bfloat16))
 

@@ -1,9 +1,10 @@
 """One run of one cell: set-up, the measured window, the check, the metrics.
 
 The harness holds no branch per cell.  The configuration file says how to
-build and compile the network; the traffic file gives the one general
-driver (``simulate.SimDriver``) its shapes and path; each metric is
-read by its own reader from the ``Context`` this module fills.
+build and compile the network, and names the modules that generate it and
+simulate it plainly (``spec.network_code``); the traffic file gives the
+one general driver (``simulate.SimDriver``) its shapes and path; each
+metric is read by its own reader from the ``Context`` this module fills.
 """
 from __future__ import annotations
 
@@ -18,7 +19,7 @@ import types
 from pathlib import Path
 from typing import Callable, Dict, List, Optional
 
-from . import netgen, spec as specmod, trace as tracemod, work
+from . import spec as specmod, trace as tracemod, work
 from .simulate import SimDriver
 from .spans import Spans
 
@@ -41,7 +42,7 @@ class Context:
     launches: List                 # launch spans that ended in the window
     ran: List                      # every launch span of the traced period
     trace: Optional["tracemod.Reduced"]
-    spec: netgen.NetSpec
+    spec: object                   # the configuration's NetSpec
     exe: object                    # the NetworkExecutable (shapes only)
     peaks: dict
     traffic: dict
@@ -49,16 +50,29 @@ class Context:
 
 def _compile(cfg: dict, net, spans: Spans):
     """The switching compile of ``net`` by the prejudging classifier,
-    trained on the configuration's stored data set."""
+    trained on the configuration's stored data set.
+
+    A CSR projection over the program's dense element cap compiles serial,
+    by the program's own rule for such projections
+    (``repro.scaffold.cerebellum.scaffold_policies``): the parallel
+    paradigm stores its weights dense.
+    """
     from repro.core import SwitchingCompiler, train_switch_classifier
     from repro.core.dataset import ParadigmDataset
+    from repro.core.layer import DENSE_ELEMENT_CAP, is_sparse
+    from repro.core.switching import CompileReport
 
     how = cfg["compile"]
     with spans.span("setup.classifier"):
         data = ParadigmDataset.load(str(specmod.config_path(how["dataset"])))
         clf, _ = train_switch_classifier(data, seed=int(how["classifier_seed"]))
     with spans.span("setup.switching"):
-        return SwitchingCompiler("classifier", clf).compile_network(net)
+        prejudged = SwitchingCompiler("classifier", clf)
+        serial = SwitchingCompiler("serial")
+        return CompileReport([
+            (serial if is_sparse(e)
+             and e.n_source * e.n_target > DENSE_ELEMENT_CAP
+             else prejudged).compile_layer(e) for e in net.layers])
 
 
 def _device(chips: int, require_tpu: bool):
@@ -143,14 +157,16 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
         config_overrides: Optional[dict] = None,
         traffic_overrides: Optional[dict] = None,
         trace_dir: Optional[Path] = None,
-        tamper: Optional[Callable] = None) -> dict:
-    """Run cell ``workload`` once; returns the result line's object.
+        tamper: Optional[Callable] = None,
+        bench: Optional[dict] = None) -> dict:
+    """Run cell ``workload`` of ``bench`` (default: ``BENCHMARK.json``)
+    once; returns the result line's object.
 
     ``tamper(driver)``, where given, runs after the window and before the
     check: the control and the fault tests put their answers in the
     program's place through it.
     """
-    cell = specmod.load_cell(workload)
+    cell = specmod.load_cell(workload, bench)
     config = specmod.merged(cell.config, config_overrides)
     traffic = specmod.merged(cell.traffic, traffic_overrides)
     devices = _device(cell.chips, require_tpu)
@@ -166,8 +182,9 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     spans.annotate_with(jax.profiler.TraceAnnotation)
     h = types.SimpleNamespace(traffic=traffic, seed=seed, spans=spans)
     with spans.span("setup.network"):
-        h.spec = netgen.generate(config)
-        h.net = netgen.to_program(h.spec)
+        generator = specmod.network_code(config, "generator")
+        h.spec = generator.generate(config)
+        h.net = generator.to_program(h.spec)
     h.report = _compile(config, h.net, spans)
     driver = SimDriver(h)
     with spans.span("setup.warmup"):
@@ -208,6 +225,9 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     attempted, failed = driver.attempted_failed()
     driver.release()
     gc.collect()
+    # the reference is the check's, so not set-up: loaded once the window
+    # has closed
+    h.reference = specmod.network_code(config, "reference")
     if tamper is not None:
         tamper(driver)
 

@@ -1,9 +1,13 @@
-"""Networks of the benchmark's configurations, generated from their files.
+"""Networks of the benchmark's configurations, generated from their files;
+the default generator, and the ``NetSpec`` every generator returns.
 
 The benchmark owns its networks: a configuration file states the recipe
-(population sizes, densities, delays, LIF constants) and this module draws
-the synapses.  Both the system under test and the plain reference are
-given the same arrays, so neither depends on how the other stores them.
+(population sizes, densities, delays, LIF constants) and a generator
+draws the synapses.  Both the system under test and the plain reference
+are given the same arrays, so neither depends on how the other stores
+them.  This module draws chains; a configuration that names another
+``"generator"`` (``chipbench/spec.py``) brings its own, such as a
+recurrent graph through ``chipbench/graph.py``.
 
 The whole network (which synapses exist, their delays, signs and
 magnitudes) is drawn from the configuration's fixed ``structure_seed``,
@@ -33,6 +37,9 @@ class Pop:
     alpha: float = 0.0
     v_th: float = 1.0
     rate: float = 0.0          # spikes per channel per step (inputs)
+    #: neuron constants a configuration's generator and its reference
+    #: share, beyond Eq. 1's ``alpha`` and ``v_th``
+    params: dict = dataclasses.field(default_factory=dict)
 
 
 @dataclasses.dataclass
@@ -61,11 +68,16 @@ class Proj:
 
 @dataclasses.dataclass
 class NetSpec:
-    """A network as plain arrays: what both sides of the check are given."""
+    """A network as plain arrays: what both sides of the check are given.
+
+    ``back_edges`` holds the indices of the projections that the program
+    routes through its one-step feedback ring (``chipbench/graph.py``);
+    a chain has none."""
 
     name: str
     pops: List[Pop]
     projs: List[Proj]
+    back_edges: Tuple[int, ...] = ()
 
     @property
     def inputs(self) -> List[Pop]:

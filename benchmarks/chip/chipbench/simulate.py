@@ -7,7 +7,8 @@ puts them on the device; the window launches
 outputs reaching the host before the next starts.  A seed-drawn uniform
 sample of ``KEPT`` of the window's launches (reservoir sampling, decided
 as each launch completes) keeps its outputs; once the window has closed,
-each kept launch is compared with the reference's run of its input.
+each kept launch is compared with the run of its input by the reference
+the configuration names (``h.reference``).
 """
 from __future__ import annotations
 
@@ -15,8 +16,6 @@ import time
 from typing import Callable, Dict, List
 
 import numpy as np
-
-from . import reference
 
 #: Input trains drawn per run, launched in turn.
 DISTINCT_INPUTS = 16
@@ -129,7 +128,7 @@ class SimDriver:
         for n, outs in sorted(self.kept.items()):
             k = self.launches[n].attrs["input"]
             if k not in refs:
-                refs[k] = reference.simulate(spec, self.inputs[k])
+                refs[k] = self.h.reference.simulate(spec, self.inputs[k])
             # every projection's view of its target population
             for e, z in zip(spec.projs, outs):
                 mismatches += int(np.count_nonzero(z != refs[k][e.post]))

@@ -12,20 +12,35 @@ Each piece is a file of its own under ``benchmarks/chip/``:
   file of the part before its first dot (``metrics/idle_share.py``), so
   one computation has one reader.
 
-Adding a cell or a metric adds files and entries; no existing file changes.
+A configuration brings its network's code by naming two modules, each a
+path under ``benchmarks/chip/``:
+
+* ``"generator"`` (default ``chipbench/netgen.py``) -- ``generate(cfg)
+  -> NetSpec`` and ``to_program(spec) -> SNNNetwork``;
+* ``"reference"`` (default ``chipbench/reference.py``) -- ``simulate(spec,
+  spikes, dtype=np.float32) -> {population: (T, B, n) uint8}``.
+
+Adding a cell, a configuration or a metric adds files and entries; no
+existing file changes.
 """
 from __future__ import annotations
 
 import dataclasses
 import importlib.util
 import json
+import re
+import sys
 from pathlib import Path
+from types import ModuleType
 from typing import Callable, Dict, List
 
 #: ``benchmarks/chip`` -- the benchmark's own directory.
 BENCH_DIR = Path(__file__).resolve().parents[1]
 #: The checkout's root, where ``BENCHMARK.json`` lives.
 ROOT = BENCH_DIR.parents[1]
+#: A configuration's network code where its file names none.
+DEFAULT_CODE = {"generator": "chipbench/netgen.py",
+                "reference": "chipbench/reference.py"}
 
 
 @dataclasses.dataclass
@@ -50,20 +65,51 @@ def load_json(path: Path) -> dict:
         return json.load(f)
 
 
+def load_module(rel: str) -> ModuleType:
+    """The module in file ``rel`` (a path under ``benchmarks/chip/``),
+    loaded once per process.
+
+    A file of a package (``chipbench/graph.py``) is imported by its dotted
+    name, so it is one object however it is reached.  Any other file is
+    loaded by its path and belongs to no package: it imports by absolute
+    name (``from chipbench import graph``), never relatively.
+    """
+    path = (BENCH_DIR / rel).resolve()
+    parts = path.relative_to(BENCH_DIR).with_suffix("").parts  # never out
+    if not path.is_file():
+        raise FileNotFoundError(f"no module at {path}")
+    if (path.parent / "__init__.py").is_file():
+        return importlib.import_module(".".join(parts))
+    name = "chipbench_file_" + re.sub(r"\W", "_", "/".join(parts))
+    if name not in sys.modules:
+        mod_spec = importlib.util.spec_from_file_location(name, path)
+        module = importlib.util.module_from_spec(mod_spec)
+        # a dataclass looks its module up by name while it is made
+        sys.modules[name] = module
+        try:
+            mod_spec.loader.exec_module(module)
+        except BaseException:
+            del sys.modules[name]
+            raise
+    return sys.modules[name]
+
+
 def load_reader(name: str) -> Callable:
     """The ``read`` function of ``metrics/<name>.py``, else of
     ``metrics/<name up to its first dot>.py``."""
-    tried = [BENCH_DIR / "metrics" / f"{n}.py"
+    tried = [f"metrics/{n}.py"
              for n in dict.fromkeys((name, name.split(".", 1)[0]))]
-    path = next((p for p in tried if p.is_file()), None)
-    if path is None:
+    rel = next((r for r in tried if (BENCH_DIR / r).is_file()), None)
+    if rel is None:
         raise FileNotFoundError(f"no reader for metric {name!r} at "
-                                f"{' or '.join(map(str, tried))}")
-    mod_spec = importlib.util.spec_from_file_location(
-        "chipbench_metric_" + name.replace(".", "_"), path)
-    module = importlib.util.module_from_spec(mod_spec)
-    mod_spec.loader.exec_module(module)
-    return module.read
+                                f"{' or '.join(tried)} under {BENCH_DIR}")
+    return load_module(rel).read
+
+
+def network_code(config: dict, role: str) -> ModuleType:
+    """The module configuration ``config`` names for ``role``
+    (``"generator"`` or ``"reference"``), or the default."""
+    return load_module(config.get(role, DEFAULT_CODE[role]))
 
 
 def _metrics_for(entries, cell: str, e2e_names, end_to_end: bool):
@@ -79,7 +125,8 @@ def _metrics_for(entries, cell: str, e2e_names, end_to_end: bool):
 
 
 def load_cell(name: str, bench: dict | None = None) -> Cell:
-    """Everything the harness needs to run cell ``name``."""
+    """Everything the harness needs to run cell ``name`` of ``bench``
+    (default: ``BENCHMARK.json``)."""
     bench = bench or load_json(ROOT / "BENCHMARK.json")
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:

@@ -14,12 +14,31 @@ the cell can have:
 (One chip: no cell has an exchange between chips to leave out.)
 """
 import time
+from pathlib import Path
 
 import jax.numpy as jnp
 
-from chipbench import control, harness
+from chipbench import control, harness, spec
 
 SOUND = "sound"
+#: Test configurations, not in ``BENCHMARK.json``.
+DATA = Path(__file__).resolve().parent / "data"
+
+
+def fixture_bench(*configs: str) -> dict:
+    """``BENCHMARK.json`` with the test configurations ``configs`` (files
+    ``tests/data/<name>.json``) added, each with one cell per traffic mix
+    of the benchmark, named ``<config>.<traffic>``."""
+    bench = spec.load_json(spec.ROOT / "BENCHMARK.json")
+    mixes = sorted({w["traffic"] for w in bench["workloads"]})
+    for name in configs:
+        rel = (DATA / f"{name}.json").relative_to(spec.ROOT).as_posix()
+        bench["configs"].append({"name": name, "source": "test fixture",
+                                 "file": rel, "reduced": [], "why": "test"})
+        bench["workloads"] += [{"name": f"{name}.{t}", "config": name,
+                                "traffic": t, "chips": 1, "why": "test"}
+                               for t in mixes]
+    return bench
 
 
 def _wrap_launches(monkeypatch, post):
@@ -69,9 +88,9 @@ def plant(monkeypatch, fault: str):
 
 
 def run(cell: str, fault: str, monkeypatch, *, seconds=1.0, seed=2**31 + 11,
-        config=None, traffic=None) -> dict:
+        config=None, traffic=None, bench=None) -> dict:
     tamper = plant(monkeypatch, fault)
     return harness.run(cell, seed, seconds, False,
                        t_process=time.perf_counter(), require_tpu=False,
                        config_overrides=config, traffic_overrides=traffic,
-                       tamper=tamper)
+                       tamper=tamper, bench=bench)

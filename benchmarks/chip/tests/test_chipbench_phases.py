@@ -98,13 +98,14 @@ def test_unpaired_dispatches_give_no_offset_and_no_shift():
 
 
 @pytest.mark.parametrize("cell, carry, reads", [
-    ("gesture.scan_t256", True, 2), ("gesture.window_t256", False, 4)])
+    ("gesture.scan_t256", False, 2), ("gesture.window_t256", False, 4)])
 def test_traced_run_on_the_cpu_reads_every_phase(cell, carry, reads):
     """One traced run at 32 steps a launch: every launch records its phases
-    in the trace.  The scan builds a carry and reads its two outputs; the
-    temporal path builds none and reads pass counts and residual first.
-    The CPU has no device plane, so no offset pairs and the gaps are named
-    unshifted."""
+    in the trace.  No launch builds a carry on the host: the scan's zero
+    carry is made inside its launch program, and the temporal path's too.
+    The scan reads its two outputs; the temporal path reads pass counts
+    and residual first.  The CPU has no device plane, so no offset pairs
+    and the gaps are named unshifted."""
     out = launch_phases.measure(cell, 2**31 + 13, require_tpu=False,
                                 traffic_overrides={"steps": 32})
     assert out["correct"] is True
