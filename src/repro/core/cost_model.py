@@ -184,6 +184,19 @@ class SerialBatchCostModel:
       magnitude above the crossover ``benchmarks/bench_temporal.py``
       measures on the CPU backend (temporal already wins at T = 16
       there).
+
+    Spike-driven constants (the form of
+    :func:`repro.core.runtime.serial_runtime.serial_project_spike`, only
+    competing for a projection whose synapses pack into one word each):
+
+    * ``spike_base`` — fixed per-step cost of the form's compaction and
+      its pass loop: the form pays only on projections of tens of
+      thousands of rows and more (measured on a TPU v5e, the ``O(B * R)``
+      scatter of the event form over 4.5e6 rows a step held a
+      256-step launch of the microcircuit past 20 s).
+    * ``spike_activity`` — the share of (lane, source) pairs assumed to
+      fire in a step; the form routes that share of the rows, each a
+      scattered accumulate, after an ``O(B * S)`` search for the spikes.
     """
 
     scatter_coeff: float = 16.0
@@ -194,6 +207,8 @@ class SerialBatchCostModel:
     temporal_coeff: float = 1.0
     temporal_base: float = 16384.0
     step_coeff: float = 64.0
+    spike_base: float = float(2 ** 18)
+    spike_activity: float = 0.01
 
     def event_cost(self, n_rows: int, batch: int) -> float:
         """Relative cost of one event-form timestep at this batch."""
@@ -204,6 +219,12 @@ class SerialBatchCostModel:
     ) -> float:
         """Relative cost of one dense-form timestep at this batch."""
         return self.mac_coeff * batch * n_source * (delay_range + 1) * n_target
+
+    def spike_cost(self, n_rows: int, n_source: int, batch: int) -> float:
+        """Relative cost of one spike-driven-form timestep at this batch."""
+        return self.spike_base + self.scatter_coeff * batch * (
+            n_source + self.spike_activity * n_rows
+        )
 
     def sparse_cost(self, n_rows: int, batch: int) -> float:
         """Relative cost of one sparse (ELL gather) timestep at this batch."""
@@ -293,9 +314,12 @@ class SerialBatchCostModel:
         batch: int,
         steps: int | None = None,
         allow_temporal: bool = True,
+        spike_ok: bool = False,
     ) -> str:
         """Cheapest serial kernel form: ``"event"``, ``"sparse"``,
-        ``"dense"`` — or ``"temporal"`` when a step count is supplied.
+        ``"dense"`` — or ``"temporal"`` when a step count is supplied,
+        and ``"spike"`` (the spike-driven form) when ``spike_ok`` says the
+        projection's synapses pack into that form's words.
 
         Without ``steps`` (the per-timestep callers) the decision is the
         exact three-way argmin it has always been.  With ``steps`` the
@@ -322,8 +346,12 @@ class SerialBatchCostModel:
         * layers over :attr:`dense_element_cap` never pick dense — the
           operand physically shouldn't exist.
 
+        * the spike-driven form wins only on large projections: its cost
+          follows the assumed share of firing sources, plus a fixed
+          per-step cost (``spike_base``) the ``O(B * R)`` forms do not pay.
+
         Ties break toward the cheaper-memory form (event < sparse <
-        dense).
+        dense < spike).
         """
         if n_rows == 0:
             return "event"       # nothing to scatter, gather, or multiply
@@ -335,6 +363,8 @@ class SerialBatchCostModel:
             costs.append(
                 ("dense", self.dense_cost(n_source, n_target, delay_range, batch))
             )
+        if spike_ok:
+            costs.append(("spike", self.spike_cost(n_rows, n_source, batch)))
         best = min(costs, key=lambda fc: fc[1])
         if steps is None or not allow_temporal:
             return best[0]
@@ -522,6 +552,8 @@ class SerialBatchCostModel:
             "temporal_coeff": self.temporal_coeff,
             "temporal_base": self.temporal_base,
             "step_coeff": self.step_coeff,
+            "spike_base": self.spike_base,
+            "spike_activity": self.spike_activity,
         }
 
 

@@ -107,6 +107,131 @@ class LIFParams:
     n_projection_type: int = 2   # excitatory / inhibitory (Table I)
 
 
+#: Fraction bits of every ``iaf_psc_exp`` propagator: an integer ``p``
+#: stands for ``p / 2**PROPAGATOR_BITS``.
+PROPAGATOR_BITS = 31
+
+
+def round_half_up(x) -> np.ndarray:
+    """``floor(x + 1/2)`` as int64: the rounding of every fixed-point
+    constant of :class:`IafPscExpParams`."""
+    return np.floor(np.asarray(x, np.float64) + 0.5).astype(np.int64)
+
+
+@dataclasses.dataclass(frozen=True)
+class IafFixed:
+    """The integer constants of one ``iaf_psc_exp`` population.
+
+    The membrane potential is held relative to ``E_L`` in units of
+    ``2**-v_bits`` mV, the synaptic current in units of ``2**-i_bits``
+    weight units; ``p22``, ``p21`` and ``p11`` are propagators with
+    :data:`PROPAGATOR_BITS` fraction bits, ``v_drive`` is ``P20 * I_e``
+    in potential units, ``t_ref`` is in steps.
+    """
+
+    p22: int
+    p21: int
+    p11: int
+    v_drive: int
+    v_th: int
+    v_reset: int
+    t_ref: int
+    i_bits: int
+
+
+@dataclasses.dataclass(frozen=True)
+class IafPscExpParams:
+    """NEST's ``iaf_psc_exp`` neuron, run in integer fixed point.
+
+    A current-based leaky integrate-and-fire neuron with one exponentially
+    decaying synaptic current (equal excitatory and inhibitory time
+    constants), a refractory period and reset to ``v_reset``.  Each step
+    follows NEST's exact-integration order::
+
+        if r == 0:  V <- P22 V + P21 I + P20 I_e      (else r <- r - 1)
+        I <- P11 I + (this step's synaptic input)
+        if V >= V_th:  spike;  V <- V_reset;  r <- t_ref
+
+    Units: ms, mV, pF, pA; ``t_ref`` in steps of ``dt``.  A synaptic weight
+    of 1 is ``weight_pa`` pA.  :meth:`fixed` gives the integer constants
+    (:class:`IafFixed`); every product of a propagator and a state is
+    ``floor((p * x + 2**30) / 2**31)``, so the update is the same bits on
+    every backend.  ``v0`` holds each neuron's initial potential in mV
+    (``None``: ``E_L``); it is a runtime operand, not part of the
+    compiled constants, so it takes no part in equality.
+    """
+
+    tau_m: float = 10.0
+    tau_syn: float = 0.5
+    c_m: float = 250.0
+    t_ref: int = 20
+    e_l: float = -65.0
+    v_reset: float = -65.0
+    v_th: float = -50.0
+    i_e: float = 0.0
+    dt: float = 0.1
+    weight_pa: float = 1.0
+    v_bits: int = 16
+    i_bits: int = 12
+    v0: Optional[np.ndarray] = dataclasses.field(
+        default=None, compare=False, hash=False, repr=False
+    )
+
+    def propagators(self) -> Tuple[float, float, float, float]:
+        """(P22, P21, P11, P20): NEST's exact propagators for one step
+        (P21 in mV per pA, P20 in mV per pA of ``I_e``)."""
+        h, tm, ts = self.dt, self.tau_m, self.tau_syn
+        p22 = np.exp(-h / tm)
+        p11 = np.exp(-h / ts)
+        p21 = ts * tm / (self.c_m * (tm - ts)) * (p22 - p11)
+        p20 = tm / self.c_m * (1.0 - p22)
+        return float(p22), float(p21), float(p11), float(p20)
+
+    def fixed(self) -> IafFixed:
+        """The integer constants of the update (see :class:`IafFixed`)."""
+        if self.tau_m == self.tau_syn:
+            raise ValueError("iaf_psc_exp needs tau_m != tau_syn")
+        p22, p21, p11, p20 = self.propagators()
+        one = 2.0 ** PROPAGATOR_BITS
+        v_unit = 2.0 ** self.v_bits
+        scale21 = self.weight_pa * 2.0 ** (self.v_bits - self.i_bits)
+        consts = IafFixed(
+            p22=int(round_half_up(p22 * one)),
+            p21=int(round_half_up(p21 * scale21 * one)),
+            p11=int(round_half_up(p11 * one)),
+            v_drive=int(round_half_up(p20 * self.i_e * v_unit)),
+            v_th=int(round_half_up((self.v_th - self.e_l) * v_unit)),
+            v_reset=int(round_half_up((self.v_reset - self.e_l) * v_unit)),
+            t_ref=int(self.t_ref),
+            i_bits=int(self.i_bits),
+        )
+        for name in ("p22", "p21", "p11"):
+            if not 0 <= getattr(consts, name) < 2 ** PROPAGATOR_BITS:
+                raise ValueError(f"iaf_psc_exp: propagator {name} outside "
+                                 f"[0, 1) at these units")
+        if consts.t_ref < 0:
+            raise ValueError("iaf_psc_exp: t_ref must be >= 0 steps")
+        return consts
+
+    def v0_fixed(self, n: int) -> np.ndarray:
+        """(n,) int32 initial potentials in the state's units."""
+        if self.v0 is None:
+            return np.zeros(n, np.int32)
+        v0 = np.asarray(self.v0, np.float64).reshape(-1)
+        if v0.shape != (n,):
+            raise ValueError(f"iaf_psc_exp: v0 has {v0.size} entries for "
+                             f"{n} neurons")
+        raw = round_half_up((v0 - self.e_l) * 2.0 ** self.v_bits)
+        if np.abs(raw).max(initial=0) >= 2 ** 31:
+            raise ValueError("iaf_psc_exp: v0 outside the int32 state")
+        return raw.astype(np.int32)
+
+
+def is_iaf(params: object) -> bool:
+    """True for a population that runs :class:`IafPscExpParams`."""
+    return isinstance(params, IafPscExpParams)
+
+
 @dataclasses.dataclass
 class SNNLayer:
     """A concrete projection: weights + delays + the derived character.
@@ -220,12 +345,14 @@ class Population:
     ``lif`` optionally pins the population's neuron parameters; when
     ``None`` they are derived from the (unique) LIF parameters of the
     projections targeting it — the chain-compatible behavior where a
-    layer's ``lif`` governs its target neurons.
+    layer's ``lif`` governs its target neurons.  A population runs Eq. 1
+    (:class:`LIFParams`) or ``iaf_psc_exp`` (:class:`IafPscExpParams`,
+    set here, never derived).
     """
 
     name: str
     size: int
-    lif: Optional[LIFParams] = None
+    lif: Optional[object] = None
 
     def validate(self) -> None:
         if not self.name:
@@ -287,10 +414,16 @@ class SparseProjection:
     """A projection stored in CSR form — only nonzero synapses exist.
 
     Rows are source neurons.  ``indptr`` is the ``(S + 1,)`` int64 row
-    pointer; ``indices`` holds each synapse's target-neuron column
-    (sorted, duplicate-free within each row); ``values`` holds the signed
-    weight (excitatory > 0, inhibitory < 0, never 0) and ``delay_values``
-    the per-synapse delay in ``[1, delay_range]``.  ``densify()`` is the
+    pointer; ``indices`` holds each synapse's target-neuron column;
+    ``values`` holds the signed weight (excitatory > 0, inhibitory < 0,
+    never 0) and ``delay_values`` the per-synapse delay in ``[0,
+    delay_range]``.  A row may repeat a column (**multapses**, as
+    fixed-total-number connectivity draws them): each entry is a synapse
+    of its own, with its own weight and delay, and every form sums them.
+    Delay 0 is allowed only on a projection the network routes through
+    its one-step feedback ring (a back-edge), where it arrives one step
+    after emission; :class:`SNNNetwork` refuses it anywhere else.
+    ``densify()`` is the
     exact inverse of :meth:`from_dense` on any dense projection, and the
     differential harness (``tests/test_sparse_equivalence.py``) pins every
     sparse launch path bit-identical to the densified numpy oracle.
@@ -318,10 +451,14 @@ class SparseProjection:
     post: Optional[str] = None
 
     def __post_init__(self) -> None:
+        # integer arrays keep a narrow integer dtype they come in (a 3e8
+        # synapse network is held once, not widened); coo() widens
         self.indptr = np.asarray(self.indptr, dtype=np.int64)
-        self.indices = np.asarray(self.indices, dtype=np.int64)
-        self.values = np.asarray(self.values, dtype=np.float64)
-        self.delay_values = np.asarray(self.delay_values, dtype=np.int64)
+        self.indices = _integer_array(self.indices)
+        self.values = np.asarray(self.values)
+        if self.values.dtype.kind not in "iuf":
+            self.values = self.values.astype(np.float64)
+        self.delay_values = _integer_array(self.delay_values)
         if self.indptr.shape != (self.n_source + 1,):
             raise ValueError(
                 f"sparse projection {self.name!r}: indptr shape "
@@ -346,20 +483,13 @@ class SparseProjection:
                     f"sparse projection {self.name!r}: explicit zero weight "
                     f"— drop the entry instead"
                 )
-            if self.delay_values.min() < 1 or (
+            if self.delay_values.min() < 0 or (
                 int(self.delay_values.max()) > self.delay_range
             ):
                 raise ValueError(
                     f"sparse projection {self.name!r}: delay outside "
-                    f"[1, {self.delay_range}]"
+                    f"[0, {self.delay_range}]"
                 )
-            for r in range(self.n_source):
-                row = self.indices[self.indptr[r]:self.indptr[r + 1]]
-                if row.size > 1 and (np.diff(row) <= 0).any():
-                    raise ValueError(
-                        f"sparse projection {self.name!r}: row {r} columns "
-                        f"must be strictly increasing (sorted, no duplicates)"
-                    )
         if not self.pre or not self.post:
             raise ValueError(
                 f"sparse projection {self.name!r} needs pre= and post= "
@@ -369,6 +499,13 @@ class SparseProjection:
     @property
     def n_synapses(self) -> int:
         return int(self.indptr[-1])
+
+    @property
+    def min_delay(self) -> int:
+        """Smallest synaptic delay (``delay_range`` when empty)."""
+        if not self.n_synapses:
+            return self.delay_range
+        return int(self.delay_values.min())
 
     def density(self) -> float:
         return self.n_synapses / float(self.n_source * self.n_target)
@@ -382,19 +519,28 @@ class SparseProjection:
         )
 
     def coo(self) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
-        """``(src, tgt, weight, delay)`` per synapse, row-major order."""
+        """``(src, tgt, weight, delay)`` per synapse, row-major order:
+        int64, int64, float64, int64."""
         src = np.repeat(
             np.arange(self.n_source, dtype=np.int64), np.diff(self.indptr)
         )
-        return src, self.indices, self.values, self.delay_values
+        return (src, self.indices.astype(np.int64, copy=False),
+                self.values.astype(np.float64, copy=False),
+                self.delay_values.astype(np.int64, copy=False))
 
     def densify(self, max_elements: Optional[int] = None) -> Projection:
         """The exact dense :class:`Projection` this CSR form represents.
 
         Unconnected slots get weight 0 and delay 1 (ignored, matching the
-        dense generators).  Subject to the same element cap as
-        :func:`random_projection` — the oracle densifies small fixtures,
-        it must never be the accidental path to a 100 MB array.
+        dense generators).  Multapses of one (pre, post) pair are summed
+        into one synapse when they share a delay; a dense layer holds one
+        delay per pair, so a pair whose multapses differ in delay is
+        refused (the delay-stacked forms, :func:`delay_stacked_weights
+        <repro.core.runtime.reference.delay_stacked_weights>` and the
+        parallel compile, sum them per delay instead).  Subject to the
+        same element cap as :func:`random_projection` — the oracle
+        densifies small fixtures, it must never be the accidental path
+        to a 100 MB array.
         """
         _check_dense_budget(
             self.n_source, self.n_target, max_elements,
@@ -403,7 +549,23 @@ class SparseProjection:
         weights = np.zeros((self.n_source, self.n_target), dtype=np.float64)
         delays = np.ones((self.n_source, self.n_target), dtype=np.int64)
         src, tgt, w, d = self.coo()
-        weights[src, tgt] = w
+        lo = np.full((self.n_source, self.n_target), self.delay_range + 1)
+        hi = np.full((self.n_source, self.n_target), -1)
+        np.minimum.at(lo, (src, tgt), d)
+        np.maximum.at(hi, (src, tgt), d)
+        if (lo[src, tgt] != hi[src, tgt]).any():
+            raise ValueError(
+                f"SparseProjection.densify({self.name!r}): multapses of one "
+                f"(pre, post) pair differ in delay; a dense layer holds one "
+                f"delay per pair"
+            )
+        np.add.at(weights, (src, tgt), w)
+        if (weights[src, tgt] == 0.0).any():
+            raise ValueError(
+                f"SparseProjection.densify({self.name!r}): multapses of one "
+                f"pair sum to weight 0, which a dense layer reads as no "
+                f"synapse"
+            )
         delays[src, tgt] = d
         return Projection(
             weights=weights, delays=delays, delay_range=self.delay_range,
@@ -435,8 +597,8 @@ class SparseProjection:
                     pre: str, post: str, name: str) -> "SparseProjection":
         """The CSR sub-matrix ``[r0:r1, c0:c1]`` — no densification.
 
-        The tiling pass slices population blocks this way; columns inside
-        each row are already sorted, so masking preserves CSR invariants.
+        The tiling pass slices population blocks this way; masking keeps
+        each row's entries in their order, multapses included.
         """
         starts = self.indptr[r0:r1]
         stops = self.indptr[r0 + 1:r1 + 1]
@@ -458,6 +620,11 @@ class SparseProjection:
             delay_range=self.delay_range, lif=self.lif,
             name=name, pre=pre, post=post,
         )
+
+
+def _integer_array(a) -> np.ndarray:
+    a = np.asarray(a)
+    return a if a.dtype.kind in "iu" else a.astype(np.int64)
 
 
 def is_sparse(proj: object) -> bool:
@@ -539,7 +706,10 @@ class SNNNetwork:
     runtime cascades forward edges within a timestep in topological order
     and routes back-edges through a one-step-delayed feedback ring, so a
     spike crossing a back-edge of synaptic delay ``d`` arrives ``d + 1``
-    steps after emission.
+    steps after emission.  A back-edge may therefore carry delay 0 (a CSR
+    :class:`SparseProjection` only): a network with a published delay
+    ``d`` on a back-edge stores ``d - 1`` there and arrives at exactly
+    ``d``.  Delay 0 on a forward edge is refused.
 
     ``forced_back_edges`` (graph form only) lists projection indices that
     must be treated as back-edges regardless of where their endpoints land
@@ -778,6 +948,14 @@ class SNNNetwork:
             )
             for p in range(n)
         )
+        for i, e in enumerate(self._projections):
+            if (i not in self._back_edges and is_sparse(e)
+                    and e.min_delay < 1):
+                raise ValueError(
+                    f"projection {e.name!r}: delay 0 is only allowed on a "
+                    f"back-edge (routed through the one-step feedback "
+                    f"ring, where it arrives one step after emission)"
+                )
         sources = [p for p in range(n) if not self._in_edges[p]]
         if not sources:
             raise ValueError(
@@ -900,8 +1078,9 @@ class SNNNetwork:
         self._ensure_graph()
         return sum(self._populations[i].size for i in self._input_indices)
 
-    def population_lif(self, pop: int) -> LIFParams:
-        """Effective LIF parameters for one population (declared index).
+    def population_lif(self, pop: int):
+        """Effective neuron parameters for one population (declared index):
+        :class:`LIFParams` or :class:`IafPscExpParams`.
 
         The population's own ``lif`` wins; otherwise the unique ``lif``
         shared by its incoming projections (chain-compatible: a layer's

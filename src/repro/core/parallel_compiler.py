@@ -37,7 +37,7 @@ from .cost_model import (
     total,
 )
 from .hw import SpiNNaker2Config, DEFAULT_S2
-from .layer import SNNLayer, is_sparse
+from .layer import SNNLayer, _check_dense_budget, is_sparse
 
 _SLICE_HEADER_BYTES = 8
 _BLOCK_INDEX_BYTES = 4
@@ -59,7 +59,7 @@ def _pad(n: int, m: int) -> int:
 class WDMSlice:
     """One optimized delay slice of the weight-delay-map."""
 
-    delay: int                    # 1-based synaptic delay of this slice
+    delay: int                    # synaptic delay of this slice (0 only on a back-edge)
     col_sources: np.ndarray       # (n_cols,) source ids of compressed columns
     matrix: np.ndarray            # (rows_padded, cols_padded) int8
     block_nz: np.ndarray          # (n_row_blocks,) bool — stored blocks
@@ -73,7 +73,7 @@ class SubordinateAssignment:
     part_index: int
     row_block_start: int
     row_block_stop: int
-    chunks: List[Tuple[int, int, int]]   # (slice_idx, col_start, col_stop)
+    chunks: np.ndarray                   # (n, 3): slice_idx, col_start, col_stop
     wdm_bytes: int
     cost: Dict[str, float]
 
@@ -114,10 +114,42 @@ class ParallelProgram:
 # slice statistics (shared by the fast counter and the full compiler)
 # ---------------------------------------------------------------------------
 
-def _slice_stats(layer: SNNLayer, opts: OptFlags, hw: SpiNNaker2Config):
+def _csr_slices(layer) -> Dict[int, Tuple[np.ndarray, np.ndarray, np.ndarray]]:
+    """Per delay of a CSR projection: its synapses' (sources, targets,
+    weights), multapses of one (delay, pre, post) summed into one weight.
+    No (S, T) array is built."""
+    src, tgt, w, d = layer.coo()
+    key = (d * layer.n_source + src) * layer.n_target + tgt
+    uniq, inv = np.unique(key, return_inverse=True)
+    merged = np.bincount(inv, weights=w, minlength=uniq.size)
+    keep = merged != 0.0
+    uniq, merged = uniq[keep], merged[keep]
+    dly, rest = np.divmod(uniq, layer.n_source * layer.n_target)
+    s_of, t_of = np.divmod(rest, layer.n_target)
+    out = {}
+    for s in np.unique(dly):
+        m = dly == s
+        out[int(s)] = (s_of[m], t_of[m], merged[m])
+    return out
+
+
+def merged_weights_fit_int8(layer) -> bool:
+    """Do the projection's weights, summed per (delay, pre, post), fit
+    the parallel paradigm's signed int8 MAC operands exactly?"""
+    if is_sparse(layer):
+        ws = [w for _, _, w in _csr_slices(layer).values()]
+        w = np.concatenate(ws) if ws else np.zeros(0)
+    else:
+        w = layer.weights[layer.connectivity()]
+    return bool(np.all((np.abs(w) <= 127) & (w == np.round(w))))
+
+
+def _slice_stats(layer, opts: OptFlags, hw: SpiNNaker2Config):
     """Per-delay-slice column counts and nonzero row-block masks."""
-    conn = layer.connectivity()
     n_blocks = math.ceil(layer.n_target / hw.mac_rows)
+    if is_sparse(layer):
+        return _csr_slice_stats(layer, opts, hw, n_blocks), n_blocks
+    conn = layer.connectivity()
     stats = []   # (delay, col_mask, block_nz)
     for s in range(1, layer.delay_range + 1):
         mask = conn & (layer.delays == s)
@@ -136,6 +168,30 @@ def _slice_stats(layer: SNNLayer, opts: OptFlags, hw: SpiNNaker2Config):
             block_nz = np.ones(n_blocks, dtype=bool)
         stats.append((s, col_mask, block_nz))
     return stats, n_blocks
+
+
+def _csr_slice_stats(layer, opts: OptFlags, hw: SpiNNaker2Config, n_blocks):
+    """:func:`_slice_stats` of a CSR projection, from its synapses; delay
+    0 (on a back-edge) is a slice like any other."""
+    slices = _csr_slices(layer)
+    first = 0 if layer.min_delay == 0 else 1
+    stats = []
+    for s in range(first, layer.delay_range + 1):
+        src, tgt, _ = slices.get(s, (np.zeros(0, np.int64),) * 3)
+        if opts.prune_delay_slices and not src.size:
+            continue
+        if opts.compress_zero_cols:
+            col_mask = np.zeros(layer.n_source, dtype=bool)
+            col_mask[src] = True
+        else:
+            col_mask = np.ones(layer.n_source, dtype=bool)
+        if opts.fold_zero_row_blocks:
+            block_nz = np.zeros(n_blocks, dtype=bool)
+            block_nz[np.unique(tgt // hw.mac_rows)] = True
+        else:
+            block_nz = np.ones(n_blocks, dtype=bool)
+        stats.append((s, col_mask, block_nz))
+    return stats
 
 
 def _slice_col_bytes(n_cols: int, opts: OptFlags, hw: SpiNNaker2Config) -> int:
@@ -177,40 +233,34 @@ def _two_stage_split(
 
     best = None
     for T in range(1, n_blocks + 1):
-        # equal contiguous block partition into T parts
+        # equal contiguous block partition into T parts (all parts of one
+        # T at once: the per-part budget is elementwise in the rows)
         edges = np.linspace(0, n_blocks, T + 1).round().astype(int)
         edges = np.unique(edges)
         if len(edges) - 1 != T:
             continue
-        counts, byte_list, parts = [], [], []
-        feasible = True
-        for p in range(T):
-            b0, b1 = int(edges[p]), int(edges[p + 1])
-            rows = min(b1 * hw.mac_rows, layer.n_target) - b0 * hw.mac_rows
-            if rows <= 0:
-                continue
-            part_bytes = int(prefix[b1] - prefix[b0]) + header
-            overhead = total(
-                parallel_subordinate_overhead(
-                    rows, layer.delay_range, n_src_vertex, hw=hw
-                )
-            )
-            budget = hw.dtcm_bytes - overhead
-            if budget <= 0:
-                feasible = False
-                break
-            counts.append(max(1, math.ceil(part_bytes / budget)))
-            byte_list.append(part_bytes)
-            parts.append((b0, b1))
-        if not feasible:
+        b0, b1 = edges[:-1], edges[1:]
+        rows = np.minimum(b1 * hw.mac_rows, layer.n_target) - b0 * hw.mac_rows
+        keep = rows > 0
+        b0, b1, rows = b0[keep], b1[keep], rows[keep]
+        part_bytes = (prefix[b1] - prefix[b0]).astype(np.int64) + header
+        overhead = sum(
+            parallel_subordinate_overhead(
+                rows, layer.delay_range, n_src_vertex, hw=hw
+            ).values()
+        )
+        budget = hw.dtcm_bytes - overhead
+        if (budget <= 0).any():
             continue
-        tot = sum(counts)
-        if best is None or tot < best[0] or (tot == best[0] and T < best[1]):
-            best = (tot, T, parts, counts, byte_list)
+        counts = np.maximum(1, np.ceil(part_bytes / budget)).astype(np.int64)
+        tot = int(counts.sum())
+        if best is None or tot < best[0]:
+            best = (tot, T, b0, b1, counts, part_bytes)
     if best is None:
         raise ValueError("no feasible two-stage split (DTCM too small)")
-    _tot, T, parts, counts, byte_list = best
-    return T, parts, counts, byte_list
+    _tot, T, b0, b1, counts, part_bytes = best
+    parts = [(int(a), int(b)) for a, b in zip(b0, b1)]
+    return T, parts, [int(c) for c in counts], [int(x) for x in part_bytes]
 
 
 def parallel_pe_count_exact(
@@ -226,10 +276,11 @@ def parallel_pe_count_exact(
     accurately estimated").
     """
     if is_sparse(layer):
-        # the parallel paradigm materializes dense MAC slices by design, so
-        # CSR inputs densify here — and the dense element cap still applies:
-        # a projection that only fits sparse cannot be compiled parallel
-        layer = layer.densify()
+        # the parallel paradigm materializes dense MAC slices by design,
+        # so the dense element cap applies to CSR inputs too: a
+        # projection that only fits sparse cannot be compiled parallel
+        _check_dense_budget(layer.n_source, layer.n_target, None,
+                            f"parallel compile of {layer.name!r}")
     stats, n_blocks = _slice_stats(layer, opts, hw)
     n_src_vertex = max(1, math.ceil(layer.n_source / hw.max_neurons_per_pe))
     dom_cost = total(
@@ -252,18 +303,35 @@ def compile_parallel(
     hw: SpiNNaker2Config = DEFAULT_S2,
     opts: OptFlags = OptFlags(),
 ) -> ParallelProgram:
-    if is_sparse(layer):
-        # dense MAC slices are the parallel paradigm's whole storage format;
-        # densify (subject to the element cap) rather than pretend otherwise
-        layer = layer.densify()
+    sparse = is_sparse(layer)
+    if sparse:
+        # dense MAC slices are the parallel paradigm's whole storage
+        # format (subject to the element cap); each delay's slice is
+        # scatter-added from the synapses, multapses summed
+        _check_dense_budget(layer.n_source, layer.n_target, None,
+                            f"parallel compile of {layer.name!r}")
+        by_delay = _csr_slices(layer)
+    if not merged_weights_fit_int8(layer):
+        raise ValueError(
+            f"parallel compile of {layer.name!r}: weights summed per "
+            f"(delay, pre, post) do not fit the int8 MAC operands; "
+            f"compile it serial"
+        )
     stats, n_blocks = _slice_stats(layer, opts, hw)
     n_src_vertex = max(1, math.ceil(layer.n_source / hw.max_neurons_per_pe))
 
     slices: List[WDMSlice] = []
     for s, col_mask, block_nz in stats:
         cols = np.flatnonzero(col_mask)
-        mask = layer.connectivity() & (layer.delays == s)
-        w = np.where(mask, layer.weights, 0.0)[cols, :].T  # (n_target, n_cols)
+        if sparse:
+            src, tgt, wv = by_delay.get(s, (np.zeros(0, np.int64),) * 3)
+            col_of = np.full(layer.n_source, -1, np.int64)
+            col_of[cols] = np.arange(len(cols))
+            w = np.zeros((layer.n_target, len(cols)))
+            w[tgt, col_of[src]] = wv                       # pairs are unique
+        else:
+            mask = layer.connectivity() & (layer.delays == s)
+            w = np.where(mask, layer.weights, 0.0)[cols, :].T  # (n_target, n_cols)
         rows_p = _pad(layer.n_target, hw.mac_rows) if opts.mac_align else layer.n_target
         cols_p = _pad(len(cols), hw.mac_cols) if opts.mac_align else len(cols)
         mat = np.zeros((max(rows_p, layer.n_target), max(cols_p, len(cols))), dtype=np.int8)
@@ -293,30 +361,42 @@ def compile_parallel(
         )
         # stage 2: walk (slice, 16-col group) units round-robin into chunks of
         # ~equal bytes so every chunk fits the budget.
-        units: List[Tuple[int, int, int, int]] = []  # (slice, c0, c1, bytes)
-        for k, sl in enumerate(slices):
-            n_cols = len(sl.col_sources)
-            if n_cols == 0:
-                continue
-            step = hw.mac_cols
-            nz_blocks = int(sl.block_nz[b0:b1].sum())
-            if nz_blocks == 0:
-                continue
-            for c0 in range(0, n_cols, step):
-                c1 = min(c0 + step, n_cols)
-                u_bytes = nz_blocks * hw.mac_rows * (
-                    _pad(c1 - c0, step) if opts.mac_align else (c1 - c0)
-                )
-                units.append((k, c0, c1, u_bytes))
+        step = hw.mac_cols
+        n_cols = np.array([len(sl.col_sources) for sl in slices], np.int64)
+        nz_blocks = np.array([int(sl.block_nz[b0:b1].sum()) for sl in slices],
+                             np.int64)
+        live = (n_cols > 0) & (nz_blocks > 0)
+        groups = np.where(live, -(-n_cols // step), 0)
+        k_of = np.repeat(np.arange(len(slices)), groups)
+        first = np.cumsum(groups) - groups
+        c0 = (np.arange(int(groups.sum())) - np.repeat(first, groups)) * step
+        c1 = np.minimum(c0 + step, n_cols[k_of])
+        width = -(-(c1 - c0) // step) * step if opts.mac_align else c1 - c0
+        u_bytes = nz_blocks[k_of] * hw.mac_rows * width
         per_chunk = max(1, math.ceil(max(1, part_bytes) / max(1, n_chunks)))
-        chunk_lists: List[List[Tuple[int, int, int]]] = [[] for _ in range(n_chunks)]
-        chunk_bytes = [0] * n_chunks
-        ci = 0
-        for (k, c0, c1, u_bytes) in units:
-            if chunk_bytes[ci] + u_bytes > per_chunk and ci < n_chunks - 1:
+        # a unit goes to the current chunk while it fits, else (one step
+        # on) to the next; the last chunk takes the rest
+        chunk_of = np.zeros(len(u_bytes), np.int64)
+        cum = np.concatenate([[0], np.cumsum(u_bytes)])
+        pos, ci, filled = 0, 0, 0
+        while pos < len(u_bytes):
+            if ci == n_chunks - 1:
+                chunk_of[pos:] = ci
+                break
+            j = max(pos, int(np.searchsorted(
+                cum, cum[pos] - filled + per_chunk, side="right")) - 1)
+            chunk_of[pos:j] = ci
+            pos = j
+            if pos < len(u_bytes):
                 ci += 1
-            chunk_lists[ci].append((k, c0, c1))
-            chunk_bytes[ci] += u_bytes
+                chunk_of[pos] = ci
+                filled = int(u_bytes[pos])
+                pos += 1
+        chunk_bytes = np.bincount(chunk_of, weights=u_bytes,
+                                  minlength=n_chunks).astype(np.int64)
+        units = np.stack([k_of, c0, c1], axis=1)      # chunk_of is sorted
+        chunk_lists = np.split(units, np.cumsum(
+            np.bincount(chunk_of, minlength=n_chunks))[:-1])
         for ci in range(n_chunks):
             cost_ci = dict(cost)
             cost_ci["wdm"] = float(chunk_bytes[ci])
@@ -326,7 +406,7 @@ def compile_parallel(
                     row_block_start=b0,
                     row_block_stop=b1,
                     chunks=chunk_lists[ci],
-                    wdm_bytes=chunk_bytes[ci],
+                    wdm_bytes=int(chunk_bytes[ci]),
                     cost=cost_ci,
                 )
             )

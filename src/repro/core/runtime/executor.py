@@ -76,9 +76,10 @@ import numpy as np
 
 from ... import tracing
 from ...distributed import sharding as shardlib
+from ...kernels.iaf_psc_exp import iaf_layout, iaf_psc_exp
 from ...kernels.lif_update import lif_update
 from ..cost_model import DEFAULT_SERIAL_BATCH_COST, SerialBatchCostModel
-from ..layer import LIFParams, SNNNetwork
+from ..layer import IafFixed, LIFParams, SNNNetwork, is_iaf
 from ..parallel_compiler import ParallelProgram
 from ..serial_compiler import SerialProgram
 from ..switching import CompiledLayer, CompileReport
@@ -86,15 +87,20 @@ from .parallel_runtime import (
     ParallelExecutable,
     lower_parallel,
     parallel_project,
+    parallel_ring_depth,
 )
 from .serial_runtime import (
     SerialExecutable,
+    SpikeLayout,
     dense_serial_weights,
     lower_serial,
     serial_project,
     serial_project_dense,
     serial_project_sparse,
+    serial_project_spike,
     sparse_serial_operands,
+    spike_layout,
+    spike_serial_operands,
 )
 from .temporal_runtime import (
     TemporalReport,
@@ -145,11 +151,17 @@ class LayerMeta:
     #: Event volume: synaptic rows (serial) / WDM columns (parallel); feeds
     #: the serial dense-fallback crossover decision.
     n_rows: int = 0
+    #: Parallel: some column has delay 0 (a back-edge), so the spike
+    #: history is written before it is read, one slot deeper.
+    zero_delay: bool = False
+    #: Serial: the spike-driven form's word layout and per-lane pass
+    #: capacities, or ``None`` where the synapses do not pack.
+    spike: Optional[SpikeLayout] = None
 
     @property
     def ring_depth(self) -> int:
         """Spike-history ring depth; >= 1 even for degenerate programs."""
-        return max(1, self.delay_range)
+        return parallel_ring_depth(self.delay_range, self.zero_delay)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -175,6 +187,12 @@ class GraphPlan:
     proj_tgt: Tuple[int, ...]             # per projection: target pop
     proj_back: Tuple[bool, ...]           # per projection: back-edge?
     back_sources: Tuple[int, ...]         # pops carried in the feedback ring
+    #: per pop: the fixed-point constants of an ``iaf_psc_exp`` population,
+    #: ``None`` for Eq. 1 (empty: every population runs Eq. 1)
+    pop_iaf: Tuple[Optional[IafFixed], ...] = ()
+
+    def iaf(self, p: int) -> Optional[IafFixed]:
+        return self.pop_iaf[p] if self.pop_iaf else None
 
 
 def _graph_plan(net: SNNNetwork) -> GraphPlan:
@@ -184,9 +202,13 @@ def _graph_plan(net: SNNNetwork) -> GraphPlan:
     input_set = frozenset(input_pops)
     update_order = tuple(p for p in net.topo_order if p not in input_set)
     alpha, vth = [0.0] * n, [1.0] * n
+    iaf: List[Optional[IafFixed]] = [None] * n
     for p in update_order:
         lif = net.population_lif(p)
-        alpha[p], vth[p] = float(lif.alpha), float(lif.v_th)
+        if is_iaf(lif):
+            iaf[p] = lif.fixed()
+        else:
+            alpha[p], vth[p] = float(lif.alpha), float(lif.v_th)
     endpoints = net.endpoints
     proj_src = tuple(net.population_index(pre) for pre, _ in endpoints)
     return GraphPlan(
@@ -205,6 +227,7 @@ def _graph_plan(net: SNNNetwork) -> GraphPlan:
             i in net.back_edges for i in range(len(endpoints))
         ),
         back_sources=tuple(sorted({proj_src[i] for i in net.back_edges})),
+        pop_iaf=tuple(iaf) if any(c is not None for c in iaf) else (),
     )
 
 
@@ -227,48 +250,100 @@ def _chain_plan(metas: Tuple[LayerMeta, ...]) -> GraphPlan:
 
 
 def _layer_params(exe) -> Tuple[jnp.ndarray, ...]:
-    """The traced operand arrays of one lowered layer (a pytree leaf tuple)."""
+    """The operand arrays of one lowered layer (a pytree leaf tuple); a
+    serial layer's are host arrays, put on the device by the form that
+    runs them."""
     if isinstance(exe, SerialExecutable):
         return (exe.row_weight, exe.row_delay, exe.row_src, exe.row_tgt)
     return (exe.wdm_stack, exe.col_source, exe.col_delay)
 
 
+#: Counters the launch program computes when a projection runs the
+#: spike-driven form, in the order of its counter array's rows.
+LAUNCH_COUNTERS = ("events.routed", "spikes.emitted", "route.passes")
+#: Each counter is carried as two int32 digits, ``lo + hi * 2**24``, so a
+#: launch may count past 2**31.
+_DIGIT = 24
+
+
+def _count_add(acc: jnp.ndarray, step: jnp.ndarray) -> jnp.ndarray:
+    """``acc`` (n, 2) digits plus this step's (n,) int32 counts."""
+    lo = acc[:, 0] + step
+    return jnp.stack([lo & ((1 << _DIGIT) - 1), acc[:, 1] + (lo >> _DIGIT)],
+                     axis=1)
+
+
+def read_counters(counts) -> dict:
+    """Host totals of a launch's counter digits (any leading axes, such as
+    the vmapped path's lanes)."""
+    a = np.asarray(counts).astype(np.int64).reshape(-1, len(LAUNCH_COUNTERS), 2)
+    tot = (a[:, :, 0] + (a[:, :, 1] << _DIGIT)).sum(axis=0)
+    return {name: int(v) for name, v in zip(LAUNCH_COUNTERS, tot)}
+
+
+def _iaf_to_rows(x: jnp.ndarray, rows: int) -> jnp.ndarray:
+    """(B, n) -> the (rows, 128) lane layout of :func:`iaf_layout`."""
+    flat = x.reshape(-1)
+    return jnp.pad(flat, (0, rows * 128 - flat.size)).reshape(rows, 128)
+
+
 def _init_graph_carry(
-    plan: GraphPlan, metas: Tuple[LayerMeta, ...], batch: int
+    plan: GraphPlan,
+    metas: Tuple[LayerMeta, ...],
+    forms: Tuple[str, ...],
+    batch: int,
+    neuron_params: Tuple[Tuple[jnp.ndarray, ...], ...] = (),
 ):
-    """Fresh zero scan state: per-projection rings, per-population LIF
+    """Fresh scan state: per-projection rings, per-population neuron
     state, and the back-edge feedback ring.  Called only while a jit
-    entry is traced, so the zeros are part of the launch program; the
-    ``carry.traced`` counter records each such trace."""
+    entry is traced, so the state is made inside the launch program; the
+    ``carry.traced`` counter records each such trace.
+
+    An Eq. 1 population starts from zero ``(v, z)``; an ``iaf_psc_exp``
+    population from its initial potentials (``neuron_params``, one
+    ``(v0,)`` per such population in update order, tiled over the batch)
+    with zero current and no refractory count, its ``(v, i, r)`` in the
+    kernel's lane layout and no ``z``.  A spike-driven projection's ring
+    is int32."""
     tracing.count("carry.traced")
     proj = []
-    for meta in metas:
+    for meta, form in zip(metas, forms):
         if meta.paradigm == "serial":
             proj.append(
                 jnp.zeros(
-                    (meta.delay_range + 1, batch, meta.n_target), jnp.float32
+                    (meta.delay_range + 1, batch, meta.n_target),
+                    jnp.int32 if form == "spike" else jnp.float32,
                 )
             )
         else:
             proj.append(
                 jnp.zeros((meta.ring_depth, meta.n_source, batch), jnp.int8)
             )
-    pop_v = tuple(
-        jnp.zeros((batch, plan.pop_sizes[p]), jnp.float32)
-        for p in plan.update_order
-    )
+    pop_v = []
+    v0s = iter(neuron_params)
+    for p in plan.update_order:
+        n = plan.pop_sizes[p]
+        if plan.iaf(p) is None:
+            pop_v.append(jnp.zeros((batch, n), jnp.float32))
+            continue
+        rows = iaf_layout(batch, n)
+        (v0,) = next(v0s)
+        v = _iaf_to_rows(jnp.broadcast_to(v0, (batch, n)), rows)
+        zero = jnp.zeros((rows, 128), jnp.int32)
+        pop_v.append((v, zero, zero))
     # spike state crossing timesteps is int8 (spikes are exactly 0/1, the
     # f32<->int8 casts are bit-exact) — same layout as the parallel spike
     # history rings, 4x less carried-state traffic
-    pop_z = tuple(
+    pop_z = [
         jnp.zeros((batch, plan.pop_sizes[p]), jnp.int8)
+        if plan.iaf(p) is None else ()
         for p in plan.update_order
-    )
+    ]
     feedback = tuple(
         jnp.zeros((batch, plan.pop_sizes[s]), jnp.int8)
         for s in plan.back_sources
     )
-    return (tuple(proj), pop_v, pop_z, feedback)
+    return (tuple(proj), tuple(pop_v), tuple(pop_z), feedback)
 
 
 def _read_bytes(arrays) -> int:
@@ -279,12 +354,17 @@ def _read_bytes(arrays) -> int:
 def _scan_network(
     plan: GraphPlan,
     metas: Tuple[LayerMeta, ...],
-    forms: Tuple[str, ...],       # per proj: "event" | "sparse" | "dense" | "-"
+    forms: Tuple[str, ...],       # per proj: "event" | "sparse" | "dense" | "spike" | "-"
     interpret: bool | None,
     params: List[Tuple[jnp.ndarray, ...]],
     spikes: jnp.ndarray,          # (T, B, n_input) f32
     valid_steps: jnp.ndarray | None = None,   # (B,) i32 true length per request
 ):
+    """One scan over the train: ``(outs, counts)``, one train per
+    non-input population in update order, and the launch's counter
+    digits (:data:`LAUNCH_COUNTERS`) where a projection runs the
+    spike-driven form, else ``()``.  ``params`` holds each projection's
+    operands, then one ``(v0,)`` per ``iaf_psc_exp`` population."""
     # Step-count mask: batch slot b is live while t < valid_steps[b].  The
     # mask is applied entirely OUTSIDE the scan (one vectorized multiply on
     # the input train and one per population's stacked output) so masking
@@ -304,16 +384,20 @@ def _scan_network(
 
     vz_slot = {p: k for k, p in enumerate(plan.update_order)}
     fb_slot = {s: k for k, s in enumerate(plan.back_sources)}
+    counting = "spike" in forms
+    batch = spikes.shape[1]
 
     def step(carry, x_t):
-        t, proj_states, pop_v, pop_z, feedback = carry
+        t, proj_states, pop_v, pop_z, feedback = carry[:5]
         pop_out = [None] * len(plan.pop_sizes)
         for p, (a, b) in zip(plan.input_pops, plan.input_slices):
             pop_out[p] = x_t if (a, b) == (0, x_t.shape[1]) else x_t[:, a:b]
         new_proj = list(proj_states)
         new_v, new_z = list(pop_v), list(pop_z)
+        step_counts = [jnp.int32(0)] * len(LAUNCH_COUNTERS)
         for p in plan.update_order:
             k = vz_slot[p]
+            iaf = plan.iaf(p)
             i_nb = None               # summed current, (n_target, B)
             for ei in plan.in_edges[p]:
                 meta, form = metas[ei], forms[ei]
@@ -326,7 +410,17 @@ def _scan_network(
                     if plan.proj_back[ei]
                     else pop_out[plan.proj_src[ei]]
                 )
-                if meta.paradigm == "serial":
+                if form == "spike":
+                    ring, i_bt, (events, passes) = serial_project_spike(
+                        *params[ei], proj_states[ei], x, t,
+                        n_target=meta.n_target, layout=meta.spike,
+                    )
+                    new_proj[ei] = ring
+                    step_counts[0] += events
+                    step_counts[2] += jnp.maximum(passes - 1, 0)
+                    i_e = i_bt if iaf is not None else i_bt.T.astype(
+                        jnp.float32)
+                elif meta.paradigm == "serial":
                     proj_fn = {
                         "dense": serial_project_dense,
                         "sparse": serial_project_sparse,
@@ -341,10 +435,27 @@ def _scan_network(
                 else:
                     hist, i_e = parallel_project(
                         *params[ei], proj_states[ei], x, t,
-                        interpret=interpret,
+                        interpret=interpret, write_first=meta.zero_delay,
                     )
                     new_proj[ei] = hist
+                if iaf is not None and i_e.dtype != jnp.int32:
+                    # integer sums of integer weights: exact in f32
+                    i_e = i_e.T.astype(jnp.int32)
                 i_nb = i_e if i_nb is None else i_nb + i_e
+            if iaf is not None:
+                n = plan.pop_sizes[p]
+                rows = iaf_layout(batch, n)
+                v, i_syn, r = pop_v[k]
+                v, i_syn, r, z = iaf_psc_exp(
+                    _iaf_to_rows(i_nb, rows), v, i_syn, r, consts=iaf,
+                    interpret=interpret,
+                )
+                new_v[k] = (v, i_syn, r)
+                z_bn = z.reshape(-1)[: batch * n].reshape(batch, n)
+                pop_out[p] = z_bn.astype(jnp.float32)
+                if counting:
+                    step_counts[1] += z_bn.sum()
+                continue
             v_new, z_new = lif_update(
                 i_nb, pop_v[k].T, pop_z[k].T.astype(jnp.float32),
                 alpha=plan.pop_alpha[p], v_th=plan.pop_vth[p],
@@ -355,6 +466,8 @@ def _scan_network(
             # what same-step forward projections consume
             new_v[k], new_z[k] = v_new.T, z_new.T.astype(jnp.int8)
             pop_out[p] = z_new.T
+            if counting:
+                step_counts[1] += z_new.sum().astype(jnp.int32)
         new_feedback = tuple(
             pop_out[s].astype(jnp.int8) for s in plan.back_sources
         )
@@ -363,16 +476,23 @@ def _scan_network(
         # launch wrappers expand to the per-projection API view outside
         # the scan (aliased, no extra device buffers)
         outs = tuple(pop_out[p] for p in plan.update_order)
-        carry = (
+        new = (
             t + 1, tuple(new_proj), tuple(new_v), tuple(new_z), new_feedback
         )
-        return carry, outs
+        if counting:
+            new += (_count_add(carry[5], jnp.stack(step_counts)),)
+        return new, outs
 
-    init = (jnp.int32(0),) + _init_graph_carry(plan, metas, spikes.shape[1])
-    _, outs = jax.lax.scan(step, init, spikes)
+    n_proj = len(metas)
+    init = (jnp.int32(0),) + _init_graph_carry(
+        plan, metas, forms, batch, tuple(params[n_proj:])
+    )
+    if counting:
+        init += (jnp.zeros((len(LAUNCH_COUNTERS), 2), jnp.int32),)
+    final, outs = jax.lax.scan(step, init, spikes)
     if live is not None:
         outs = tuple(z * live for z in outs)
-    return outs
+    return outs, (final[5] if counting else ())
 
 
 def _batched_scan(
@@ -389,21 +509,21 @@ def _batched_scan(
     Each request runs its own width-1 scan, from the width-1 zero state
     that scan builds inside its lane; vmap batches them.  The per-lane
     ``valid_steps`` mask is preserved — lanes with 0 valid steps (padded
-    slots) emit exact zeros just like the fused path.
+    slots) emit exact zeros just like the fused path.  Counter digits
+    come back per lane.
     """
 
     def one(sp, vs):              # sp (T, n_in), vs () i32 or None
-        outs = _scan_network(
+        outs, counts = _scan_network(
             plan, metas, forms, interpret, params, sp[:, None, :],
             None if vs is None else vs[None],
         )
-        return tuple(z[:, 0] for z in outs)
+        return tuple(z[:, 0] for z in outs), counts
 
     if valid_steps is None:
-        return jax.vmap(lambda sp: one(sp, None), in_axes=1, out_axes=1)(
-            spikes
-        )
-    return jax.vmap(one, in_axes=(1, 0), out_axes=1)(spikes, valid_steps)
+        return jax.vmap(lambda sp: one(sp, None), in_axes=1,
+                        out_axes=(1, 0))(spikes)
+    return jax.vmap(one, in_axes=(1, 0), out_axes=(1, 0))(spikes, valid_steps)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -475,6 +595,7 @@ def _temporal_subplan(plan: GraphPlan, block: Tuple[int, ...]):
         proj_tgt=plan.proj_tgt,
         proj_back=plan.proj_back,
         back_sources=plan.back_sources,
+        pop_iaf=plan.pop_iaf,
     )
     return tuple(ext), sub
 
@@ -539,7 +660,7 @@ def _temporal_network(
     if tplan.block:
         aug = [pop_out[s] for s in tplan.ext_sources]
         aug = aug[0] if len(aug) == 1 else jnp.concatenate(aug, axis=2)
-        block_outs = _scan_network(
+        block_outs, _ = _scan_network(
             tplan.sub_plan, metas, forms, interpret, params, aug, None,
         )
         for p, z in zip(tplan.block, block_outs):
@@ -607,6 +728,16 @@ class NetworkExecutable:
         self._dense = {}     # layer index -> (d_slots, S, T) dense operand
         self._sparse = {}    # layer index -> (ell_val, ell_idx) ELL operands
         self._temporal = {}  # layer index -> whole-train dense operand
+        self._event = {}     # layer index -> event-form device operands
+        self._spike = {}     # layer index -> (row_len, rows) spike operands
+        #: One ``(v0,)`` device operand per ``iaf_psc_exp`` population, in
+        #: update order (set by :meth:`build`).
+        self.neuron_params: Tuple[Tuple[jnp.ndarray, ...], ...] = ()
+        #: Per launch whose counters were read (``run``): the
+        #: :data:`LAUNCH_COUNTERS` totals, in launch order.  Only a network
+        #: with a spike-driven projection counts.
+        self.launch_counts: List[dict] = []
+        self._counts = None  # the last launch's counter digits, unread
         self._nonneg = {}    # layer index -> all weights >= 0? (mode pick)
         self._tplan = None   # cached TemporalPlan (topology, not placement)
         self._mesh = None    # set by shard(); None = identity fallback
@@ -635,6 +766,7 @@ class NetworkExecutable:
         ):
             exe = get_layer_executable(compiled, layer.lif)
             tgt = plan.proj_tgt[i]
+            serial = isinstance(exe, SerialExecutable)
             metas.append(
                 LayerMeta(
                     paradigm=compiled.paradigm,
@@ -645,16 +777,23 @@ class NetworkExecutable:
                     v_th=plan.pop_vth[tgt],
                     n_rows=int(
                         exe.row_weight.shape[0]
-                        if isinstance(exe, SerialExecutable)
+                        if serial
                         else exe.col_source.shape[0]
                     ),
+                    zero_delay=False if serial else exe.zero_delay,
+                    spike=spike_layout(exe) if serial else None,
                 )
             )
             params.append(_layer_params(exe))
-        return cls(
+        handle = cls(
             tuple(metas), params, name=getattr(net, "name", "snn"),
             plan=plan, report=report,
         )
+        handle.neuron_params = tuple(
+            (jnp.asarray(net.population_lif(p).v0_fixed(plan.pop_sizes[p])),)
+            for p in plan.update_order if plan.iaf(p) is not None
+        )
+        return handle
 
     @property
     def n_input(self) -> int:
@@ -666,22 +805,31 @@ class NetworkExecutable:
         self, batch: int, serial_form: str = "auto"
     ) -> Tuple[str, ...]:
         """Per-projection kernel form at this batch: "event" | "sparse" |
-        "dense" ("-" = parallel).
+        "dense" | "spike" ("-" = parallel).
 
         ``serial_form`` forces every serial projection onto one form
-        ("event" / "sparse" / "dense"); "auto" asks the cost model's
-        three-way argmin per projection
-        (:meth:`~repro.core.cost_model.SerialBatchCostModel.choose_form`).
-        Forcing "dense" on a projection over the cost model's element cap
-        raises — the dense operand physically shouldn't exist; every form
-        is bit-identical on outputs, so the choice only moves throughput.
+        ("event" / "sparse" / "dense" / "spike"); "auto" asks the cost
+        model's argmin per projection
+        (:meth:`~repro.core.cost_model.SerialBatchCostModel.choose_form`),
+        in which the spike-driven form competes wherever the projection's
+        synapses pack into its words (``LayerMeta.spike``).  Forcing
+        "dense" on a projection over the cost model's element cap raises —
+        the dense operand physically shouldn't exist — as does forcing
+        "spike" where the synapses do not pack; every form is
+        bit-identical on outputs, so the choice only moves throughput.
         """
-        if serial_form not in ("auto", "event", "sparse", "dense"):
+        if serial_form not in ("auto", "event", "sparse", "dense", "spike"):
             raise ValueError(f"unknown serial_form {serial_form!r}")
         forms = []
         for meta in self.metas:
             if meta.paradigm != "serial":
                 forms.append("-")
+            elif serial_form == "spike" and meta.spike is None:
+                raise ValueError(
+                    "serial_form='spike' forced on a projection whose "
+                    "synapses do not pack into the spike-driven form's "
+                    "32-bit words"
+                )
             elif serial_form != "auto":
                 if serial_form == "dense" and not self.cost_model.dense_fits(
                     meta.n_source, meta.n_target, meta.delay_range
@@ -699,22 +847,53 @@ class NetworkExecutable:
                     self.cost_model.choose_form(
                         meta.n_rows, meta.n_source, meta.n_target,
                         meta.delay_range, batch,
+                        spike_ok=meta.spike is not None,
                     )
                 )
         return tuple(forms)
+
+    def _serial_exe(self, i: int) -> SerialExecutable:
+        meta, p = self.metas[i], self.params[i]
+        return SerialExecutable(
+            n_source=meta.n_source, n_target=meta.n_target,
+            delay_range=meta.delay_range,
+            row_weight=p[0], row_delay=p[1], row_src=p[2], row_tgt=p[3],
+            lif=LIFParams(alpha=meta.alpha, v_th=meta.v_th),
+        )
+
+    def _event_param(self, i: int) -> Tuple[jnp.ndarray, ...]:
+        """The layer's event-form operands on the device, cached."""
+        ops = self._event.get(i)
+        if ops is None:
+            axes = _param_axes(self.metas[i], "event")
+            ops = tuple(
+                self._place(jnp.asarray(a), ax)
+                for a, ax in zip(self.params[i], axes)
+            )
+            self._event[i] = ops
+        return ops
+
+    def _spike_param(self, i: int) -> Tuple[jnp.ndarray, ...]:
+        """The layer's spike-driven operands ``(row_len, rows)``, built
+        once and cached."""
+        ops = self._spike.get(i)
+        if ops is None:
+            meta = self.metas[i]
+            with tracing.span("lower.spike", rows=meta.n_rows,
+                              sources=meta.n_source):
+                row_len, rows = spike_serial_operands(
+                    self._serial_exe(i), meta.spike
+                )
+                ops = (jnp.asarray(row_len), jnp.asarray(rows))
+            self._spike[i] = ops
+        return ops
 
     def _dense_param(self, i: int) -> Tuple[jnp.ndarray, ...]:
         """The layer's dense-form operand, built once and cached."""
         w = self._dense.get(i)
         if w is None:
-            meta, p = self.metas[i], self.params[i]
-            exe = SerialExecutable(
-                n_source=meta.n_source, n_target=meta.n_target,
-                delay_range=meta.delay_range,
-                row_weight=p[0], row_delay=p[1], row_src=p[2], row_tgt=p[3],
-                lif=LIFParams(alpha=meta.alpha, v_th=meta.v_th),
-            )
-            w = jnp.asarray(dense_serial_weights(exe))
+            meta = self.metas[i]
+            w = jnp.asarray(dense_serial_weights(self._serial_exe(i)))
             w = self._place(w, _param_axes(meta, "dense")[0])
             self._dense[i] = w
         return (w,)
@@ -723,14 +902,8 @@ class NetworkExecutable:
         """The layer's ELL (sparse-form) operands, built once and cached."""
         ell = self._sparse.get(i)
         if ell is None:
-            meta, p = self.metas[i], self.params[i]
-            exe = SerialExecutable(
-                n_source=meta.n_source, n_target=meta.n_target,
-                delay_range=meta.delay_range,
-                row_weight=p[0], row_delay=p[1], row_src=p[2], row_tgt=p[3],
-                lif=LIFParams(alpha=meta.alpha, v_th=meta.v_th),
-            )
-            val, idx = sparse_serial_operands(exe)
+            meta = self.metas[i]
+            val, idx = sparse_serial_operands(self._serial_exe(i))
             axes = _param_axes(meta, "sparse")
             ell = (
                 self._place(jnp.asarray(val), axes[0]),
@@ -752,6 +925,12 @@ class NetworkExecutable:
         """The (cached) temporal decomposition of this graph plan."""
         tp = self._tplan
         if tp is None:
+            if self.plan.pop_iaf:
+                raise ValueError(
+                    "the temporal path's closed forms are those of Eq. 1; "
+                    "this network has iaf_psc_exp populations — run it "
+                    "through the step scan (temporal=False)"
+                )
             pre, block, post = _temporal_split(self.plan)
             if block:
                 ext, sub = _temporal_subplan(self.plan, block)
@@ -843,16 +1022,20 @@ class NetworkExecutable:
         return (w,)
 
     def _params_for(self, forms: Tuple[str, ...]) -> List[Tuple]:
+        """Each projection's operands for its form, then the neuron
+        operands of the ``iaf_psc_exp`` populations."""
         per_form = {
+            "event": self._event_param,
             "dense": self._dense_param,
             "sparse": self._sparse_param,
+            "spike": self._spike_param,
             "temporal": self._temporal_param,
             "temporal_sparse": self._sparse_param,
         }
         return [
             per_form[form](i) if form in per_form else p
             for i, (form, p) in enumerate(zip(forms, self.params))
-        ]
+        ] + list(self.neuron_params)
 
     def _record_forms(
         self, path: str, batch: int, forms: Tuple[str, ...]
@@ -906,6 +1089,8 @@ class NetworkExecutable:
             self._dense.clear()
             self._sparse.clear()
             self._temporal.clear()
+            self._event.clear()
+            self._spike.clear()
             self._fns.clear()
             if self.report is not None:
                 self.report.placement = assignment
@@ -933,6 +1118,8 @@ class NetworkExecutable:
         self._dense.clear()
         self._sparse.clear()
         self._temporal.clear()
+        self._event.clear()
+        self._spike.clear()
         self._fns.clear()
         return self
 
@@ -987,9 +1174,11 @@ class NetworkExecutable:
                 )
 
             def checked(params, spikes, valid_steps):
-                outs, aux = inner(params, spikes, valid_steps), ()
-                if path == "temporal":   # + the fixed point's pass counts
-                    outs, aux = outs
+                # + the fixed point's pass counts (temporal), or the
+                # launch's counter digits (a spike-driven projection)
+                outs, aux = inner(params, spikes, valid_steps)
+                if path != "temporal":
+                    aux = (aux,) if len(aux) else ()
                 # in-graph output self-check: every spike entry must be
                 # exactly 0.0 or 1.0 (subsumes finiteness — NaN and Inf
                 # equal neither), reduced to one scalar the launch
@@ -1020,7 +1209,8 @@ class NetworkExecutable:
             )
             params = self._params_for(forms)
         with tracing.span("launch.dispatch"):
-            outs, _, self.last_check = fn(params, spikes, valid_steps)
+            outs, aux, self.last_check = fn(params, spikes, valid_steps)
+        self._counts = aux[0] if aux else None
         # per-population device trains -> the per-projection API view
         # (entry i = projection i's target population; fan-in entries
         # alias the same array)
@@ -1188,14 +1378,24 @@ class NetworkExecutable:
             launch = self.run_temporal
         else:
             launch = self.run_batched if batched else self.run_device
+        self._counts = None
         outs = launch(
             spikes, valid_steps=valid_steps, interpret=interpret,
             serial_form=serial_form,
         )
-        # single host sync, after the whole network finished on device
-        with tracing.span("launch.sync", what="outputs", arrays=len(outs),
-                          bytes=_read_bytes(outs)):
-            return [np.asarray(z) for z in outs]
+        counts, self._counts = self._counts, None
+        # single host sync, after the whole network finished on device;
+        # the counters (a spike-driven network's) come in the same read
+        arrays = list(outs) + ([counts] if counts is not None else [])
+        with tracing.span("launch.sync", what="outputs", arrays=len(arrays),
+                          bytes=_read_bytes(arrays)):
+            host = [np.asarray(a) for a in arrays]
+        if counts is not None:
+            totals = read_counters(host.pop())
+            for name, n in totals.items():
+                tracing.count(name, n)
+            self.launch_counts.append(totals)
+        return host
 
 
 class OutputValidationError(ValueError):

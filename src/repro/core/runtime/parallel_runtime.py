@@ -46,11 +46,20 @@ class ParallelExecutable:
     col_source: jnp.ndarray   # (C,) i32 input-merging-table: column -> source
     col_delay: jnp.ndarray    # (C,) i32 reversed-order: column -> delay
     lif: LIFParams
+    #: Some column has delay 0 (a back-edge): the ring is one slot deeper
+    #: and this step's spikes are written before the read.
+    zero_delay: bool = False
 
     @property
     def ring_depth(self) -> int:
         """Spike-history ring depth; >= 1 even for degenerate programs."""
-        return max(1, self.delay_range)
+        return parallel_ring_depth(self.delay_range, self.zero_delay)
+
+
+def parallel_ring_depth(delay_range: int, zero_delay: bool) -> int:
+    """Slots of a parallel projection's spike-history ring: ``delay_range``
+    (>= 1), one more when a delay-0 column reads this step's spikes."""
+    return delay_range + 1 if zero_delay else max(1, delay_range)
 
 
 def lower_parallel(
@@ -82,17 +91,19 @@ def lower_parallel(
         col_source=jnp.asarray(col_source, jnp.int32),
         col_delay=jnp.asarray(col_delay, jnp.int32),
         lif=lif or LIFParams(),
+        zero_delay=bool(col_delay.size and col_delay.min() == 0),
     )
 
 
-@partial(jax.jit, static_argnames=("interpret",))
+@partial(jax.jit, static_argnames=("interpret", "write_first"))
 def parallel_project(
     wdm_stack, col_source, col_delay,
-    x_hist: jnp.ndarray,      # (max(1, D), S, B) int8 spike history ring
+    x_hist: jnp.ndarray,      # (ring_depth, S, B) int8 spike history ring
     x_t: jnp.ndarray,         # (B, S) f32 spikes at t
     t: jnp.ndarray,
     *,
     interpret: bool | None = None,
+    write_first: bool = False,
 ):
     """Dominant-PE + MXU half of ONE projection.
 
@@ -100,10 +111,14 @@ def parallel_project(
     written in, and the ``(n_target, B)`` input current the target
     population consumes at ``t``.  The LIF update lives with the
     population so converging projections sum their currents first.
+    ``write_first`` (a program with delay-0 columns, whose ring is one
+    slot deeper) writes ``x_t`` before the read, so delay 0 reads it.
     """
     # the allocated ring IS the truth for the depth (clamped >= 1 at
     # allocation via ring_depth), so the index arithmetic cannot drift
     d, n_source = x_hist.shape[0], x_hist.shape[1]
+    if write_first:
+        x_hist = x_hist.at[t % d].set(x_t.T.astype(jnp.int8))
     # dominant PE: stacked input via merging table + reversed order; one
     # flat row gather on the (depth * n_source, batch) ring view
     slot = (t - col_delay) % d                       # (C,)
@@ -113,8 +128,9 @@ def parallel_project(
     i_t = spike_wdm_matmul(
         wdm_stack, stacked, interpret=interpret
     ).astype(jnp.float32)                            # (T, B)
-    # write x_t into the history ring AFTER the read (delays are >= 1)
-    x_hist = x_hist.at[t % d].set(x_t.T.astype(jnp.int8))
+    if not write_first:
+        # write x_t into the history ring AFTER the read (delays are >= 1)
+        x_hist = x_hist.at[t % d].set(x_t.T.astype(jnp.int8))
     return x_hist, i_t
 
 

@@ -22,7 +22,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..layer import LIFParams, SNNLayer, is_sparse
+from ..layer import (
+    PROPAGATOR_BITS,
+    IafFixed,
+    LIFParams,
+    SNNLayer,
+    is_iaf,
+    is_sparse,
+)
 
 
 @jax.tree_util.register_dataclass
@@ -44,25 +51,61 @@ def init_state(batch: int, n_target: int, delay_range: int) -> LIFState:
     )
 
 
-def delay_stacked_weights(layer: SNNLayer) -> np.ndarray:
-    """(delay_range, n_source, n_target) float32: slice d-1 holds delay-d weights.
+def delay_slot_weights(layer: SNNLayer) -> np.ndarray:
+    """(delay_range + 1, n_source, n_target) float32: slice d holds the
+    delay-d weights (slice 0 is empty but on a back-edge with delay 0).
 
     Accepts dense layers and CSR
     :class:`~repro.core.layer.SparseProjection` storage alike — the oracle
     *densifies internally* (it is the brute-force ground truth, not a
     scalable path), so sparse fixtures diff against exactly the same
-    dense per-delay tensors their densified twins produce.
+    dense per-delay tensors their densified twins produce.  Multapses of
+    one (delay, pre, post) are summed.
     """
-    out = np.zeros((layer.delay_range, layer.n_source, layer.n_target), np.float32)
+    out = np.zeros(
+        (layer.delay_range + 1, layer.n_source, layer.n_target), np.float32
+    )
     if is_sparse(layer):
         src, tgt, w, d = layer.coo()
-        out[d - 1, src, tgt] = w
+        np.add.at(out, (d, src, tgt), w)
         return out
     conn = layer.connectivity()
     for d in range(1, layer.delay_range + 1):
         m = conn & (layer.delays == d)
-        out[d - 1][m] = layer.weights[m]
+        out[d][m] = layer.weights[m]
     return out
+
+
+def delay_stacked_weights(layer: SNNLayer) -> np.ndarray:
+    """(delay_range, n_source, n_target) float32: slice d-1 holds delay-d
+    weights (:func:`delay_slot_weights` without its delay-0 slice, which
+    only a back-edge may use)."""
+    out = delay_slot_weights(layer)
+    if out[0].any():
+        raise ValueError(f"{layer.name!r} has delay-0 synapses, which only "
+                         f"a back-edge of a network may carry")
+    return out[1:]
+
+
+def iaf_psc_exp_int64(i_in, v, i_syn, r, c: IafFixed):
+    """One fixed-point ``iaf_psc_exp`` step in numpy int64 arithmetic —
+    the same formulas as :func:`repro.kernels.iaf_psc_exp.iaf_psc_exp_ref`,
+    with each propagator product taken whole and rounded half up.
+    Returns ``(v', i_syn', r', z)`` as int64 arrays."""
+    i_in, v, i_syn, r = (np.asarray(a, np.int64) for a in (i_in, v, i_syn, r))
+    half = np.int64(1) << (PROPAGATOR_BITS - 1)
+
+    def mul(x, p):
+        return (x * np.int64(p) + half) >> PROPAGATOR_BITS
+
+    free = r == 0
+    v = np.where(free, mul(v, c.p22) + mul(i_syn, c.p21) + c.v_drive, v)
+    r = np.where(free, r, r - 1)
+    i_syn = mul(i_syn, c.p11) + (i_in << c.i_bits)
+    z = v >= c.v_th
+    v = np.where(z, c.v_reset, v)
+    r = np.where(z, c.t_ref, r)
+    return v, i_syn, r, z.astype(np.int64)
 
 
 @partial(jax.jit, static_argnames=("delay_range",))
@@ -131,7 +174,11 @@ def run_graph_reference(net, spikes: np.ndarray) -> list:
       timestep (the one-step-delayed feedback path), so a back-edge spike
       of synaptic delay ``d`` arrives ``d + 1`` steps after emission;
     * a population sums the currents of all its in-projections before one
-      LIF update (``v' = i + alpha*v - z*v_th``; ``z' = v' >= v_th``);
+      LIF update (``v' = i + alpha*v - z*v_th``; ``z' = v' >= v_th``), or
+      one ``iaf_psc_exp`` step in int64 (:func:`iaf_psc_exp_int64`) from
+      its initial potentials;
+    * a back-edge may carry delay 0 (arriving one step after emission),
+      and multapses are summed per delay;
     * multi-input graphs consume the concatenated ``(T, B, n_input)``
       train — each input population reads its ``net.input_slices``
       columns, exactly like the fused executor.
@@ -153,9 +200,7 @@ def run_graph_reference(net, spikes: np.ndarray) -> list:
     idx = {p.name: i for i, p in enumerate(net.populations)}
     sizes = [p.size for p in net.populations]
     endpoints = net.endpoints
-    w_delay = [
-        delay_stacked_weights(e).astype(np.float32) for e in net.projections
-    ]
+    w_delay = [delay_slot_weights(e) for e in net.projections]
     d_slots = [e.delay_range + 1 for e in net.projections]
     rings = [
         np.zeros((d_slots[i], B, e.n_target), np.float32)
@@ -163,6 +208,13 @@ def run_graph_reference(net, spikes: np.ndarray) -> list:
     ]
     v = {p: np.zeros((B, sizes[p]), np.float32) for p in range(len(sizes))}
     z = {p: np.zeros((B, sizes[p]), np.float32) for p in range(len(sizes))}
+    iaf = {}                                 # pop -> (consts, [v, i, r])
+    for p in net.topo_order:
+        if p not in set(net.input_indices) and is_iaf(net.population_lif(p)):
+            params = net.population_lif(p)
+            v0 = np.broadcast_to(params.v0_fixed(sizes[p]), (B, sizes[p]))
+            zero = np.zeros((B, sizes[p]), np.int64)
+            iaf[p] = (params.fixed(), [v0.astype(np.int64), zero, zero])
     prev = [np.zeros((B, s), np.float32) for s in sizes]
     pop_trains = [np.zeros((T, B, s), np.float32) for s in sizes]
     input_set = set(net.input_indices)
@@ -174,8 +226,6 @@ def run_graph_reference(net, spikes: np.ndarray) -> list:
         for p in net.topo_order:
             if p in input_set:
                 continue
-            lif = net.population_lif(p)
-            alpha, v_th = np.float32(lif.alpha), np.float32(lif.v_th)
             i_tot = np.zeros((B, sizes[p]), np.float32)
             for ei in net.in_edges[p]:
                 e = net.projections[ei]
@@ -186,10 +236,18 @@ def run_graph_reference(net, spikes: np.ndarray) -> list:
                     "bs,dst->dbt", x, w_delay[ei]
                 ).astype(np.float32)
                 ring = rings[ei]
-                for d in range(e.delay_range):
-                    ring[(t + 1 + d) % d_slots[ei]] += contrib[d]
+                for d in range(e.delay_range + 1):
+                    ring[(t + d) % d_slots[ei]] += contrib[d]
                 i_tot += ring[t % d_slots[ei]]
                 ring[t % d_slots[ei]] = 0.0
+            if p in iaf:
+                consts, state = iaf[p]
+                out = iaf_psc_exp_int64(i_tot.astype(np.int64), *state, consts)
+                state[:] = out[:3]
+                cur[p] = out[3].astype(np.float32)
+                continue
+            lif = net.population_lif(p)
+            alpha, v_th = np.float32(lif.alpha), np.float32(lif.v_th)
             v[p] = i_tot + alpha * v[p] - z[p] * v_th
             z[p] = (v[p] >= v_th).astype(np.float32)
             cur[p] = z[p]

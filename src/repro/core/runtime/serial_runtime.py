@@ -30,6 +30,14 @@ Three kernel *forms* implement that step:
   form but with batch-contiguous reads instead of a scattered accumulate,
   so it scales linearly in batch; memory ``O(nnz)`` like the event form,
   so it is the only batch-friendly form sparse giants can run.
+* :func:`serial_project_spike` — the **spike-driven** form, SpiNNaker's
+  serial paradigm taken literally: each step it finds the sources that
+  fired, gathers their synaptic rows (padded to the longest) and
+  scatter-adds each synapse's weight into its target's ring slot
+  ``t + delay``.  Its work follows the spikes (``O(B * S)`` to find them,
+  then the rows of those that fired), not ``B * R``; a step with more
+  spikes than one pass holds takes further passes, so no spike is ever
+  dropped.
 
 All weights are int8-magnitude integers, so every form accumulates
 exactly in float32 and their spike trains are **bit-identical** — which
@@ -56,45 +64,55 @@ import numpy as np
 from ... import tracing
 from ...kernels.lif_update import lif_update
 from ..layer import LIFParams, SNNLayer
-from ..serial_compiler import SerialProgram, compile_serial, unpack_rows
+from ..serial_compiler import SerialProgram, compile_serial, decode_rows
 from .reference import LIFState, init_state
 
 @dataclasses.dataclass
 class SerialExecutable:
-    """Flattened row arrays across all machine-graph cells."""
+    """Flattened row arrays across all machine-graph cells.
+
+    The arrays are host (numpy) arrays: each kernel form builds its own
+    device operands from them (the executor caches those), so a
+    projection that runs one form holds only that form's operands on the
+    device."""
 
     n_source: int
     n_target: int
     delay_range: int
-    row_weight: jnp.ndarray   # (R,) f32 signed weight
-    row_delay: jnp.ndarray    # (R,) i32 in [1, D]
-    row_src: jnp.ndarray      # (R,) i32 global source index
-    row_tgt: jnp.ndarray      # (R,) i32 global target index
+    row_weight: np.ndarray    # (R,) f32 signed weight
+    row_delay: np.ndarray     # (R,) i32 in [0, D]
+    row_src: np.ndarray       # (R,) i32 global source index
+    row_tgt: np.ndarray       # (R,) i32 global target index
     lif: LIFParams
 
 
 def lower_serial(program: SerialProgram, lif: LIFParams | None = None) -> SerialExecutable:
     """Decode packed rows of every cell into flat gather arrays."""
     tracing.count("lower.serial")
-    ws, ds_, ss, ts = [], [], [], []
-    for cell in program.cells:
-        w, d, tgt_local = unpack_rows(cell.synaptic_rows)
-        # reconstruct each row's source neuron from the address list
-        row_start, row_len = cell.address_list[:, 0], cell.address_list[:, 1]
-        src_local = np.repeat(np.arange(cell.src_size), row_len)
-        ws.append(w)
-        ds_.append(d)
-        ss.append(src_local + cell.src_start)
-        ts.append(tgt_local + cell.tgt_start)
-    cat = lambda a, dt: jnp.asarray(np.concatenate(a) if a else np.zeros(0), dt)
+    cells = program.cells
+    if cells:
+        w, d, tgt_local = decode_rows(
+            np.concatenate([c.synaptic_rows for c in cells])
+        )
+        sizes = [c.synaptic_rows.size for c in cells]
+        # each row's source neuron from its cell's address list
+        src = np.concatenate([
+            np.repeat(np.arange(c.src_start, c.src_start + c.src_size,
+                                dtype=np.int32), c.address_list[:, 1])
+            for c in cells
+        ])
+        tgt = tgt_local + np.repeat(
+            np.array([c.tgt_start for c in cells], np.int32), sizes)
+    else:
+        w = d = src = tgt = np.zeros(0)
     return SerialExecutable(
         n_source=program.n_source,
         n_target=program.n_target,
         delay_range=program.delay_range,
-        row_weight=cat(ws, jnp.float32),
-        row_delay=cat(ds_, jnp.int32),
-        row_src=cat(ss, jnp.int32),
-        row_tgt=cat(ts, jnp.int32),
+        row_weight=np.asarray(w, np.float32),
+        row_delay=np.asarray(d, np.int32),
+        row_src=np.asarray(src, np.int32),
+        row_tgt=np.asarray(tgt, np.int32),
         lif=lif or LIFParams(),
     )
 
@@ -393,3 +411,168 @@ def run_serial(
         step, (state, jnp.int32(0)), jnp.asarray(spikes, jnp.float32)
     )
     return np.asarray(zs)
+
+
+# -- the spike-driven form ----------------------------------------------------
+
+#: Synapse slots of a full pass of the spike-driven form, per lane: it
+#: takes up as many spikes as padded rows fit (at least one).
+FULL_SLOTS = 1 << 14
+#: Synapse slots of a light pass, taken where the step's remaining spikes
+#: fit it (most steps, away from volleys).
+LIGHT_SLOTS = 1 << 12
+
+
+@dataclasses.dataclass(frozen=True)
+class SpikeLayout:
+    """Static facts of one projection's spike-driven form.
+
+    Each synapse is one int32 word, SpiNNaker's packed synaptic row:
+    ``weight << (tgt_bits + delay_bits) | delay << tgt_bits | target``,
+    the weight signed in the top bits.  A source's row is padded to the
+    projection's longest, ``row_len`` words.  ``full`` and ``light`` are
+    the spikes a full and a light pass take up per lane.
+    """
+
+    tgt_bits: int
+    delay_bits: int
+    row_len: int
+    full: int
+    light: int
+
+
+def _bits(n: int) -> int:
+    return max(1, int(n).bit_length())
+
+
+def spike_layout(exe: SerialExecutable) -> SpikeLayout | None:
+    """The spike-driven form's layout of ``exe``, or ``None`` where a
+    synapse does not fit one int32 word (a weight that is not an integer,
+    or target, delay and weight wider than 32 bits).
+
+    The capacities come from the projection's shapes alone: a full pass
+    takes up as many spikes as rows of ``row_len`` fit
+    :data:`FULL_SLOTS`, a light pass :data:`LIGHT_SLOTS`.
+    """
+    w = np.asarray(exe.row_weight)
+    n_rows = int(w.size)
+    tgt_bits = _bits(max(0, exe.n_target - 1))
+    delay_bits = _bits(exe.delay_range)
+    if n_rows:
+        w_max = int(np.abs(w).max())
+        if not np.array_equal(w, np.round(w)):
+            return None
+        row_len = int(np.bincount(exe.row_src, minlength=1).max())
+    else:
+        w_max, row_len = 0, 1
+    if tgt_bits + delay_bits + _bits(w_max) + 1 > 32 or n_rows >= 2 ** 31:
+        return None
+    full = max(1, FULL_SLOTS // row_len)
+    return SpikeLayout(tgt_bits, delay_bits, row_len, full,
+                       min(full, max(1, LIGHT_SLOTS // row_len)))
+
+
+def spike_serial_operands(exe: SerialExecutable, layout: SpikeLayout):
+    """``(row_len, rows)``: each source's synapse count, ``(S,)`` int32,
+    and its row of synapses, each packed into one int32 word
+    (:class:`SpikeLayout`), padded with zeros to ``(S, row_len)``.  Host
+    numpy arrays."""
+    src = np.asarray(exe.row_src)
+    # a stable radix sort where the source index fits 16 bits
+    key = src.astype(np.uint16) if exe.n_source <= 1 << 16 else src
+    order = np.argsort(key, kind="stable")
+    counts = np.bincount(src, minlength=exe.n_source)
+    first = np.cumsum(counts) - counts
+    src = src[order]
+    shift = layout.tgt_bits + layout.delay_bits
+    words = np.asarray(exe.row_weight)[order].astype(np.int32) << shift
+    words |= np.asarray(exe.row_delay)[order].astype(np.int32) << layout.tgt_bits
+    words |= np.asarray(exe.row_tgt)[order].astype(np.int32)
+    rows = np.zeros((exe.n_source, layout.row_len), np.int32)
+    rows[src, np.arange(src.size) - first[src]] = words
+    return counts.astype(np.int32), rows
+
+
+def serial_project_spike(
+    row_len,             # (S,) i32 synapses of each source's row
+    rows,                # (S, L) i32 packed synapses, rows padded to L
+    ring: jnp.ndarray,   # (d_slots, B, n_target) i32 future input currents
+    x_t: jnp.ndarray,    # (B, S) spikes (0/1)
+    t: jnp.ndarray,
+    *,
+    n_target: int,
+    layout: SpikeLayout,
+):
+    """Spike-driven synaptic-current step of ONE projection.
+
+    Returns ``(ring', i_t, (events, passes))``: the ring with this step's
+    synapses routed into slot ``(t + delay) % d_slots`` and the current
+    slot cleared, the ``(B, n_target)`` int32 current read from slot ``t``
+    (after routing, so a delay-0 synapse lands in the step it is routed
+    in), and the step's synaptic events and passes.
+
+    A pass takes up the next ``light`` (where the step's remaining spikes
+    fit them) or ``full`` of the (lane, source) spikes in rank order — a
+    search of the spikes' running count finds them — gathers their rows
+    whole and scatter-adds every synapse into the ring.  Passes repeat
+    until every spike is routed, so a step's work follows its spikes and
+    none is dropped.
+    """
+    d_slots, batch = ring.shape[0], ring.shape[1]
+    n_source = x_t.shape[1]
+    lanes_src = batch * n_source
+    size = d_slots * batch * n_target
+    # running count of the step's spikes, lane-major: spike of rank k
+    # (from 0) sits at the first position whose count exceeds k
+    count = jnp.cumsum((x_t > 0).reshape(-1).astype(jnp.int32))
+    n_fired = count[-1]
+    col = jnp.arange(layout.row_len, dtype=jnp.int32)
+    shift = layout.tgt_bits + layout.delay_bits
+    t_mask = (1 << layout.tgt_bits) - 1
+    d_mask = (1 << layout.delay_bits) - 1
+
+    def route(sc):
+        """A pass over the spikes of ranks [c0, c0 + sc)."""
+        k = jnp.arange(sc, dtype=jnp.int32)
+        method = "compare_all" if sc * lanes_src <= 1 << 24 else "scan"
+
+        def go(state):
+            ring_f, c0 = state
+            live = k < n_fired - c0
+            ids = jnp.where(
+                live, jnp.searchsorted(count, c0 + k + 1, method=method), 0)
+            src = ids % n_source
+            word = rows[src]                                   # (sc, L)
+            valid = live[:, None] & (col[None, :] < row_len[src][:, None])
+            slot = (t + ((word >> layout.tgt_bits) & d_mask)) % d_slots
+            flat = (slot * batch + (ids // n_source)[:, None]) * n_target \
+                + (word & t_mask)
+            ring_f = ring_f.at[jnp.where(valid, flat, size).reshape(-1)].add(
+                (word >> shift).reshape(-1), mode="drop")
+            return ring_f, jnp.sum(jnp.where(live, row_len[src], 0))
+        return go
+
+    full = min(lanes_src, batch * layout.full)
+    light = min(full, batch * layout.light)
+
+    def step(state):
+        ring_f, c0, passes, events = state
+        is_light = n_fired - c0 <= light
+        if light < full:
+            ring_f, n = jax.lax.cond(is_light, route(light), route(full),
+                                     (ring_f, c0))
+        else:
+            ring_f, n = route(full)((ring_f, c0))
+        return (ring_f, c0 + jnp.where(is_light, light, full), passes + 1,
+                events + n)
+
+    zero = jnp.int32(0)
+    ring_f, _, passes, events = jax.lax.while_loop(
+        lambda s: s[1] < n_fired, step,
+        (ring.reshape(-1), zero, zero, zero),
+    )
+    ring = ring_f.reshape(d_slots, batch, n_target)
+    now = t % d_slots
+    i_t = ring[now]
+    ring = ring.at[now].set(0)
+    return ring, i_t, (events, passes)

@@ -33,26 +33,39 @@ from .hw import SpiNNaker2Config, DEFAULT_S2
 from .layer import LayerCharacter, SNNLayer, is_sparse
 
 # --- 32-bit synaptic row packing -------------------------------------------
-# | 31..24 weight magnitude (8b) | 23..20 delay-1 (4b) | 19 type | 18..0 index |
-_W_SHIFT, _D_SHIFT, _T_SHIFT = 24, 20, 19
-_IDX_MASK = (1 << 19) - 1
+# | 31..24 weight magnitude (8b) | 23..17 delay (7b) | 16 type | 15..0 index |
+# The delay field holds the delay itself, 0..127 steps: published delays in
+# milliseconds at sub-millisecond steps reach tens of steps, and a
+# back-edge may carry delay 0.  The index is the target's place in its
+# cell (a PE holds at most 255 neurons).
+_W_SHIFT, _D_SHIFT, _T_SHIFT = 24, 17, 16
+_D_MASK = (1 << 7) - 1
+_IDX_MASK = (1 << 16) - 1
+#: Largest delay a packed row holds.
+MAX_ROW_DELAY = _D_MASK
 
 
 def pack_rows(weights: np.ndarray, delays: np.ndarray, tgt_idx: np.ndarray) -> np.ndarray:
+    if delays.size and (delays.min() < 0 or delays.max() > MAX_ROW_DELAY):
+        raise ValueError(f"a synaptic row holds delays 0..{MAX_ROW_DELAY}")
     mag = np.abs(weights).astype(np.uint32) & 0xFF
-    dly = (delays.astype(np.uint32) - 1) & 0xF
+    dly = delays.astype(np.uint32) & _D_MASK
     typ = (weights < 0).astype(np.uint32)  # 1 = inhibitory
     idx = tgt_idx.astype(np.uint32) & _IDX_MASK
     return (mag << _W_SHIFT) | (dly << _D_SHIFT) | (typ << _T_SHIFT) | idx
 
 
+def decode_rows(rows: np.ndarray):
+    """``(weight, delay, index)`` of packed rows as float32, int32, int32."""
+    w = ((rows >> _W_SHIFT) & 0xFF).astype(np.float32)
+    np.negative(w, out=w, where=((rows >> _T_SHIFT) & 0x1).astype(bool))
+    dly = ((rows >> _D_SHIFT) & _D_MASK).astype(np.int32)
+    return w, dly, (rows & _IDX_MASK).astype(np.int32)
+
+
 def unpack_rows(rows: np.ndarray):
-    mag = (rows >> _W_SHIFT) & 0xFF
-    dly = ((rows >> _D_SHIFT) & 0xF) + 1
-    typ = (rows >> _T_SHIFT) & 0x1
-    idx = rows & _IDX_MASK
-    sign = np.where(typ == 1, -1.0, 1.0)
-    return mag.astype(np.float64) * sign, dly.astype(np.int64), idx.astype(np.int64)
+    w, dly, idx = decode_rows(rows)
+    return w.astype(np.float64), dly.astype(np.int64), idx.astype(np.int64)
 
 
 @dataclasses.dataclass
@@ -160,6 +173,16 @@ def serial_pe_count_exact(
     return int(pes)
 
 
+def _part_of(idx: np.ndarray, parts: list) -> np.ndarray:
+    """The part each index lies in, for :func:`equal_parts` sizes (the
+    larger parts first): integer arithmetic, no search."""
+    big = parts[0]
+    n_big = sum(1 for p in parts if p == big)
+    cut = n_big * big
+    small = parts[-1] if len(parts) > n_big else big
+    return np.where(idx < cut, idx // big, n_big + (idx - cut) // small)
+
+
 def compile_serial(
     layer: SNNLayer, *, hw: SpiNNaker2Config = DEFAULT_S2
 ) -> SerialProgram:
@@ -178,23 +201,50 @@ def compile_serial(
 
     sparse = is_sparse(layer)
     if sparse:
-        all_src, all_tgt, all_w, all_d = layer.coo()
-        # coo() is row-major => already sorted by (source, target), the
-        # order the dense path's nonzero() scan produces within each cell
-        cell_a = np.searchsorted(src_edges, all_src, side="right") - 1
-        cell_b = np.searchsorted(tgt_edges, all_tgt, side="right") - 1
+        # one stable sort by cell keeps each cell's synapses in the CSR's
+        # row-major order and never builds a per-cell mask over all
+        # synapses; the rows are packed once and each cell holds a view
+        # (per-row and per-target lookups, in the CSR's own dtypes: no
+        # widened copy of the synapses)
+        counts = np.diff(layer.indptr)
+        n_cells = len(src_parts) * len(tgt_parts)
+        kt = (np.int64 if n_cells > 1 << 16 else
+              np.uint16 if n_cells > 256 else np.uint8)
+        tgt_cell = _part_of(np.arange(layer.n_target), tgt_parts).astype(kt)
+        row_key = _part_of(np.arange(layer.n_source), src_parts) * len(tgt_parts)
+        key = np.repeat(row_key.astype(kt), counts)
+        key += tgt_cell[layer.indices]
+        order = np.argsort(key, kind="stable")
+        cell_bounds = np.zeros(n_cells + 1, np.int64)
+        np.cumsum(np.bincount(key, minlength=n_cells), out=cell_bounds[1:])
+        del key
+        all_src = np.repeat(
+            np.arange(layer.n_source, dtype=np.int32), counts)[order]
+        tgt = layer.indices[order]
+        packed_all = pack_rows(
+            layer.values[order], layer.delay_values[order],
+            tgt - tgt_edges[tgt_cell[tgt]].astype(tgt.dtype),
+        )
+        del order, tgt
 
     cells: List[SerialCell] = []
     for a, sp in enumerate(src_parts):
         s0 = int(src_edges[a])
+        # single projection => one master-population-table entry per
+        # source vertex; entry = (routing key, address-list offset, len).
+        # Other source vertices route to sibling cells; their entries
+        # exist in every PE's table (Table I counts n_source_vertex).
+        others = [x for x in range(n_src_vertex) if x != a]
+        mpt = np.zeros((n_src_vertex, 3), dtype=np.int64)
+        mpt[0] = (a, 0, sp)
+        mpt[1:, 0] = others
         for b, tp in enumerate(tgt_parts):
             t0 = int(tgt_edges[b])
             if sparse:
-                sel = (cell_a == a) & (cell_b == b)
-                si = all_src[sel] - s0
-                ti = all_tgt[sel] - t0
-                w_sel, d_sel = all_w[sel], all_d[sel]
-                rows_per_src = np.bincount(si, minlength=sp)
+                k = a * len(tgt_parts) + b
+                lo, hi = int(cell_bounds[k]), int(cell_bounds[k + 1])
+                rows_per_src = np.bincount(all_src[lo:hi] - s0, minlength=sp)
+                packed = packed_all[lo:hi]
                 cell_elems = sp * tp
             else:
                 w = layer.weights[s0 : s0 + sp, t0 : t0 + tp]
@@ -202,24 +252,14 @@ def compile_serial(
                 conn = w != 0.0
                 rows_per_src = conn.sum(axis=1)
                 si, ti = np.nonzero(conn)
-                w_sel, d_sel = w[si, ti], d[si, ti]
+                packed = pack_rows(w[si, ti], d[si, ti], ti)
                 cell_elems = w.size
 
-            # one block per source neuron, rows sorted by (source, target)
+            # one block per source neuron, rows sorted by source
             row_start = np.concatenate([[0], np.cumsum(rows_per_src)[:-1]])
             address_list = np.stack(
                 [row_start, rows_per_src], axis=1
             ).astype(np.int64)
-
-            packed = pack_rows(w_sel, d_sel, ti)
-
-            # single projection => one master-population-table entry per
-            # source vertex; entry = (routing key, address-list offset, len)
-            mpt = np.array([[a, 0, sp]], dtype=np.int64)
-            for extra in range(n_src_vertex - 1):
-                # other source vertices route to sibling cells; their entries
-                # exist in every PE's table (Table I counts n_source_vertex).
-                mpt = np.vstack([mpt, [extra if extra < a else extra + 1, 0, 0]])
 
             overhead = serial_pe_overhead(tp, sp, layer.delay_range, n_src_vertex, hw=hw)
             matrix_bytes = 4.0 * packed.size

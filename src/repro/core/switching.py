@@ -8,6 +8,10 @@ Policies:
 * ``classifier`` — the paper's contribution: a trained classifier prejudges
   the winning paradigm from the 4 layer characters BEFORE compiling, so only
   one compilation runs per layer (layer-granularity switching, Fig 2).
+* A projection the parallel paradigm cannot hold (over the dense element
+  cap, or weights beyond int8 once multapses are summed) compiles serial
+  under ``ideal`` and ``classifier`` without a choice
+  (:func:`parallel_can_hold`).
 
 Compilation is **per projection**: the layer character is a property of one
 projection (edge of the application graph), so arbitrary graphs — fan-in,
@@ -30,8 +34,13 @@ import numpy as np
 from .classifiers import AdaBoostClassifier, Classifier
 from .dataset import LABEL_PARALLEL, LABEL_SERIAL, ParadigmDataset
 from .hw import SpiNNaker2Config, DEFAULT_S2
-from .layer import SNNLayer, SNNNetwork
-from .parallel_compiler import OptFlags, ParallelProgram, compile_parallel
+from .layer import DENSE_ELEMENT_CAP, SNNLayer, SNNNetwork, is_sparse
+from .parallel_compiler import (
+    OptFlags,
+    ParallelProgram,
+    compile_parallel,
+    merged_weights_fit_int8,
+)
 from .serial_compiler import SerialProgram, compile_serial
 
 PARADIGM_NAMES = {LABEL_SERIAL: "serial", LABEL_PARALLEL: "parallel"}
@@ -168,6 +177,21 @@ def temporal_character(layer) -> dict:
     }
 
 
+def parallel_can_hold(layer) -> bool:
+    """Can the parallel paradigm compile ``layer`` at all?
+
+    Its weight-delay map is dense, so a CSR projection over the dense
+    element cap cannot be compiled parallel, and its MAC operands are
+    int8, so neither can a projection whose weights, summed per (delay,
+    pre, post) (multapses merged), leave [-127, 127].  The classifier
+    policy compiles such a projection serial without asking the
+    classifier, so a prejudged compile never raises.
+    """
+    if is_sparse(layer) and layer.n_source * layer.n_target > DENSE_ELEMENT_CAP:
+        return False
+    return merged_weights_fit_int8(layer)
+
+
 class SwitchingCompiler:
     """Layer-granularity paradigm switching (Fig 2, right panel)."""
 
@@ -198,6 +222,12 @@ class SwitchingCompiler:
         if self.policy == "parallel":
             prog = compile_parallel(layer, hw=self.hw, opts=self.opts)
             return self._wrap(layer, LABEL_PARALLEL, prog, 1,
+                              _program_host_bytes(prog), t0)
+        # the choosing policies compile serial, without a choice, what the
+        # parallel paradigm cannot hold at all
+        if not parallel_can_hold(layer):
+            prog = compile_serial(layer, hw=self.hw)
+            return self._wrap(layer, LABEL_SERIAL, prog, 1,
                               _program_host_bytes(prog), t0)
         if self.policy == "ideal":
             sp = compile_serial(layer, hw=self.hw)

@@ -15,7 +15,6 @@ from .cerebellum import (
     ScaffoldNetwork,
     build_cerebellum,
     compile_scaffold,
-    scaffold_policies,
 )
 from .stimulus import poisson_stimulus
 
@@ -28,5 +27,4 @@ __all__ = [
     "build_cerebellum",
     "compile_scaffold",
     "poisson_stimulus",
-    "scaffold_policies",
 ]

@@ -27,7 +27,7 @@ anatomical while everything else scales with the single ``n_neurons``
 knob.  All projections are CSR (:func:`random_sparse_projection`):
 memory scales with synapse count, and at 100k neurons several
 projections exceed the dense element cap — those **must** compile on the
-serial paradigm (:func:`scaffold_policies` encodes exactly that).
+serial paradigm (:func:`compile_scaffold`).
 
 Every draw comes from one ``np.random.default_rng`` stream per
 projection, seeded as ``seed + projection position``; same
@@ -40,11 +40,9 @@ from typing import Dict, List, Optional, Tuple
 
 from ..core.hw import DEFAULT_S2
 from ..core.layer import (
-    DENSE_ELEMENT_CAP,
     LIFParams,
     Population,
     SNNNetwork,
-    is_sparse,
     random_sparse_projection,
 )
 
@@ -56,7 +54,6 @@ __all__ = [
     "ScaffoldNetwork",
     "build_cerebellum",
     "compile_scaffold",
-    "scaffold_policies",
 ]
 
 #: Mean magnitude of the int8 weight distribution (uniform 1..127) —
@@ -324,46 +321,14 @@ def build_cerebellum(
     )
 
 
-def scaffold_policies(net: SNNNetwork) -> List[str]:
-    """Per-projection compile policy for a scaffold-scale network.
-
-    CSR projections whose dense form would break the
-    ``DENSE_ELEMENT_CAP`` can only compile on the **serial** paradigm
-    (the parallel compiler densifies); everything else gets the paper's
-    ``ideal`` two-way compile-and-measure.  The resulting mix is the
-    per-size paradigm record the scale benchmark reports.
-    """
-    policies = []
-    for e in net.projections:
-        dense_elems = e.n_source * e.n_target
-        if is_sparse(e) and dense_elems > DENSE_ELEMENT_CAP:
-            policies.append("serial")
-        else:
-            policies.append("ideal")
-    return policies
-
-
-def compile_scaffold(
-    scaffold: ScaffoldNetwork,
-    *,
-    hw=DEFAULT_S2,
-    policies: Optional[List[str]] = None,
-):
-    """Compile a scaffold with scale-aware per-projection policies.
+def compile_scaffold(scaffold: ScaffoldNetwork, *, hw=DEFAULT_S2):
+    """Compile a scaffold with the paper's two-way ``ideal`` measurement
+    per projection; a CSR projection over ``DENSE_ELEMENT_CAP`` compiles
+    serial (the parallel paradigm, which densifies, cannot hold it).
 
     Returns the :class:`~repro.core.switching.CompileReport`; the chosen
     paradigm per projection is ``[l.paradigm for l in report.layers]``.
     """
-    from ..core.switching import CompileReport, SwitchingCompiler
+    from ..core.switching import SwitchingCompiler
 
-    net = scaffold.network
-    policies = policies or scaffold_policies(net)
-    if len(policies) != len(net.projections):
-        raise ValueError(
-            f"{len(policies)} policies for {len(net.projections)} projections"
-        )
-    compilers = {p: SwitchingCompiler(p, hw=hw) for p in set(policies)}
-    return CompileReport(layers=[
-        compilers[p].compile_layer(l)
-        for p, l in zip(policies, net.layers)
-    ])
+    return SwitchingCompiler("ideal", hw=hw).compile_network(scaffold.network)

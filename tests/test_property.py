@@ -36,14 +36,14 @@ def test_equal_parts_invariants(n, cap):
 @given(
     w=st.lists(st.integers(-127, 127).filter(lambda x: x != 0),
                min_size=1, max_size=64),
-    dr=st.integers(1, 16),
+    dr=st.integers(1, 127),
 )
 @settings(max_examples=100, deadline=None)
 def test_pack_unpack_roundtrip(w, dr):
     rng = np.random.default_rng(0)
     weights = np.asarray(w, np.float64)
-    delays = rng.integers(1, dr + 1, len(w))
-    idx = rng.integers(0, 2**19, len(w))
+    delays = rng.integers(0, dr + 1, len(w))
+    idx = rng.integers(0, 2**16, len(w))
     packed = pack_rows(weights, delays, idx)
     w2, d2, i2 = unpack_rows(packed)
     np.testing.assert_array_equal(w2, weights)

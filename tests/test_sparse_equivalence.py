@@ -287,8 +287,8 @@ def test_dense_cap_rejects_oversized_densify():
 
 def test_20k_neuron_sparse_net_runs_fused_e2e():
     """A 20k-neuron, <=0.5%-dense recurrent net runs end-to-end through
-    the fused scan in sparse form — its dense (d_slots, S, T) operand
-    (1.2e9 elements) is over the cap, so sparse is the only lawful form.
+    the fused scan in CSR storage — its dense (d_slots, S, T) operand
+    (1.2e9 elements) is over the cap, so dense is not a lawful form.
     CI-sized: ~220k synapses, 4 timesteps, batch 4."""
     rng = np.random.default_rng(77)
     pin = Population("spin.in", 64)
@@ -309,16 +309,23 @@ def test_20k_neuron_sparse_net_runs_fused_e2e():
     batch = 4
     spikes = (rng.random((4, batch, 64)) < 0.5).astype(np.float32)
     outs = exe.run(spikes)
-    # auto picked sparse for the 20k recurrent edge (dense can't exist,
-    # event loses at batch 4) — and the run is observably recorded
-    assert report.serial_forms[("fused", batch)][1] == "sparse"
+    # auto picked the spike-driven form for the 20k recurrent edge (dense
+    # can't exist; event and sparse touch all 160k rows every step, the
+    # spike-driven form only the rows of the sources that fired) — and
+    # the run is observably recorded
+    assert report.serial_forms[("fused", batch)][1] == "spike"
+    assert exe.cost_model.choose_form(
+        m.n_rows, m.n_source, m.n_target, m.delay_range, batch,
+        spike_ok=True) == "spike"
     assert outs[1].shape == (4, batch, 20_000)
     assert np.isfinite(outs[2]).all()
-    # the event form is the independent cross-check at this scale (the
-    # numpy oracle would densify 20k^2 — exactly what the cap forbids)
-    evt = exe.run(spikes, serial_form="event")
-    for a, b in zip(outs, evt):
-        np.testing.assert_array_equal(a, b)
+    # the event and sparse forms are the independent cross-checks at this
+    # scale (the numpy oracle would densify 20k^2 — exactly what the cap
+    # forbids)
+    for form in ("event", "sparse"):
+        other = exe.run(spikes, serial_form=form)
+        for a, b in zip(outs, other):
+            np.testing.assert_array_equal(a, b)
     # forcing the unlawful dense form is an explicit, hinted error
     with pytest.raises(ValueError, match="sparse"):
         exe.run(spikes, serial_form="dense")

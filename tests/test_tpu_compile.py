@@ -141,3 +141,39 @@ def test_gesture_scan_compiles(one_chip, gesture, policy, path):
         32, 8, path=path, interpret=False, sharding=one_chip
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("n", [21_915, 1_065])
+def test_iaf_psc_exp_compiles(spec, n):
+    """The microcircuit's largest (L4E) and smallest (L5I) populations at
+    batch 1, in the kernel's (rows, 128) lane layout."""
+    from repro.core.layer import IafPscExpParams
+    from repro.kernels.iaf_psc_exp import iaf_layout, iaf_psc_exp
+
+    consts = IafPscExpParams(weight_pa=4.0, i_e=562.0).fixed()
+    s = spec((iaf_layout(1, n), 128), jnp.int32)
+    fn = lambda *a: iaf_psc_exp(*a, consts=consts, interpret=False)
+    assert "tpu_custom_call" in _compiled_text(fn, s, s, s, s)
+
+
+def test_spike_form_compiles(spec):
+    """The spike-driven form at the microcircuit's L2/3E -> L2/3E: 20,683
+    sources, rows padded to 2,400 synapses (4.5e7 in all), a 58-slot int32
+    ring, batch 1 (XLA only)."""
+    from repro.core.runtime.serial_runtime import (
+        SpikeLayout,
+        serial_project_spike,
+    )
+
+    layout = SpikeLayout(tgt_bits=15, delay_bits=6, row_len=2_400, full=27,
+                         light=3)
+
+    def fn(row_len, rows, ring, x):
+        return serial_project_spike(row_len, rows, ring, x, jnp.int32(3),
+                                    n_target=20_683, layout=layout)
+
+    text = _compiled_text(
+        fn, spec((20_683,), jnp.int32), spec((20_683, 2_400), jnp.int32),
+        spec((58, 1, 20_683), jnp.int32), spec((1, 20_683)),
+    )
+    assert "tpu_custom_call" not in text and "while" in text
