@@ -5,6 +5,12 @@ import pytest
 
 from repro.kernels.lif_update import lif_update, lif_update_ref
 from repro.kernels.spike_wdm_matmul import spike_wdm_matmul, spike_wdm_matmul_ref
+from repro.kernels.spike_wdm_matmul.kernel import (
+    BM_MAX,
+    VMEM_BUDGET,
+    vmem_bytes,
+    wdm_tiles,
+)
 
 RNG = np.random.default_rng(0)
 
@@ -22,9 +28,15 @@ class TestSpikeWDMMatmul:
         (4, 16, 1),          # one SpiNNaker2 MAC tile
         (128, 128, 128),     # one MXU tile
         (128, 512, 128),     # K-loop accumulation
-        (300, 700, 36),      # unaligned (padding path)
+        (300, 700, 36),      # unaligned: one ragged column block
         (1, 1, 1),           # degenerate
         (257, 1025, 129),    # prime-ish off-by-one
+        # ragged row and column blocks: bm 1056, bk 1024 (bn 256 at 129)
+        (2100, 1000, 1),
+        (2100, 1000, 8),
+        (2100, 1000, 129),
+        (1065, 3841, 1),     # one column past a 3840-column block
+        (1, 700, 8),         # a map with one row
     ])
     def test_matches_ref(self, m, k, n):
         a, x = rand_wdm(m, k), rand_spikes(k, n)
@@ -57,6 +69,21 @@ class TestSpikeWDMMatmul:
             spike_wdm_matmul_ref(
                 jnp.ones((4, 4), jnp.float32), jnp.ones((4, 4), jnp.int8)
             )
+
+    @pytest.mark.parametrize("m,k", [
+        (14395, 32744), (2948, 131316), (5479, 30681), (1065, 122480),
+        (1065, 111189), (2948, 22021), (2948, 7633),
+    ])
+    def test_tiles_fit_vmem(self, m, k):
+        """The microcircuit's 7 parallel maps at batch 1: the rule's blocks
+        fit its VMEM budget, align to the int8 (32, 128) tile, hold >= 2 MiB
+        of weights, and re-read the spike block for <= 1/8 of the map."""
+        bm, bk, bn = wdm_tiles(m, k, 1)
+        assert vmem_bytes(bm, bk, bn) <= VMEM_BUDGET
+        assert (bm == m <= BM_MAX or bm % 32 == 0) and bk % 128 == 0
+        assert bm * bk >= 2 << 20 and bn == 128
+        row_blocks, cols = -(-m // bm), -(-k // bk) * bk
+        assert row_blocks * cols * bn <= m * k / 8
 
     @pytest.mark.parametrize("bm,bn,bk", [(128, 128, 128), (128, 128, 512)])
     def test_block_shapes(self, bm, bn, bk):

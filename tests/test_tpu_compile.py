@@ -9,7 +9,9 @@ topology is described inside a module-scoped fixture, never at import,
 so every test worker collects the same tests and only the worker that
 runs this file loads the TPU library.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -101,6 +103,37 @@ def test_spike_wdm_matmul_compiles(spec, gesture, vmapped):
         assert "tpu_custom_call" in _compiled_text(
             fn, spec((m, k), jnp.int8), x
         )
+
+
+@pytest.mark.parametrize("vmapped", [False, True])
+@pytest.mark.parametrize("m,k", [(14_395, 32_744), (2_948, 131_316)])
+def test_spike_wdm_matmul_reads_map_in_place(spec, m, k, vmapped):
+    """The microcircuit's two largest parallel maps (TH->L6E, L5E->L6I) at
+    batch 1: no ``pad`` in the compiled program is as large as the map, so
+    the map is read where it is stored, not copied to the kernel's tiles;
+    only the spikes are padded."""
+    fn = lambda a, x: spike_wdm_matmul(a, x, interpret=False)
+    if vmapped:
+        fn = jax.vmap(fn, in_axes=(None, 0))
+    x = spec((1, k, 1) if vmapped else (k, 1), jnp.int8)
+    text = _compiled_text(fn, spec((m, k), jnp.int8), x)
+    assert "tpu_custom_call" in text
+    pads = [math.prod(int(d) for d in dims.split(",") if d)
+            for dims in re.findall(r"= \w+\[([\d,]*)\]\S* pad\(", text)]
+    assert pads and max(pads) < m * k, pads
+
+
+@pytest.mark.parametrize("m,k,n", [
+    (1, 700, 8),            # a one-row map: a full-extent block of 1 row
+    (300, 700, 1),          # a ragged column block
+    (2_100, 1_000, 129),    # ragged row blocks, two lane blocks' worth
+])
+def test_spike_wdm_matmul_edge_blocks_compile(spec, m, k, n):
+    """Maps whose blocks reach past their rows or columns."""
+    fn = lambda a, x: spike_wdm_matmul(a, x, interpret=False)
+    assert "tpu_custom_call" in _compiled_text(
+        fn, spec((m, k), jnp.int8), spec((k, n), jnp.int8)
+    )
 
 
 @pytest.mark.parametrize("ell_shape,n_source", [
