@@ -1,7 +1,8 @@
 """Pallas kernel micro-bench (interpret mode on CPU; TPU is the target).
 
 us_per_call is the CPU-interpret wall time — meaningful only as a
-regression guard; the TPU roofline for these kernels is in §Roofline.
+regression guard; the chip benchmark (``benchmarks/chip/``) reports their
+roofline share on a TPU.
 Also runs the SNN runtime throughput comparison (serial VPU path vs
 parallel MXU path), the runtime-level analogue of Fig 5.
 """
@@ -47,17 +48,6 @@ def run():
     )
     csv_row("kernel_lif_update_1024x128", us,
             f"gneuron_updates_per_s={1024*128/us/1e3:.2f}")
-    from repro.kernels.ssd_chunk import ssd_chunk
-    q, h, p_, n_ = 256, 24, 64, 128   # mamba2-130m production chunk
-    xs = jnp.asarray(rng.normal(size=(q, h, p_)), jnp.float32)
-    bs = jnp.asarray(rng.normal(size=(q, h, n_)), jnp.float32)
-    cs = jnp.asarray(rng.normal(size=(q, h, n_)), jnp.float32)
-    las = jnp.asarray(-abs(rng.normal(size=(q, h)) * 0.1), jnp.float32)
-    us = timeit(lambda: ssd_chunk(xs, bs, cs, las)[0].block_until_ready(),
-                iters=3)
-    flops = h * (2 * q * q * n_ + 2 * q * q * p_ + 2 * q * n_ * p_)
-    csv_row(f"kernel_ssd_chunk_{q}x{h}x{p_}x{n_}", us,
-            f"gflops_per_s={flops/us/1e3:.2f}")
 
     print("\n# SNN runtime throughput (both paradigms, batch=16, T=50)")
     lif = LIFParams(alpha=0.5, v_th=64.0)

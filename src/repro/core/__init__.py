@@ -1,7 +1,7 @@
 # The paper's primary contribution: serial/parallel SNN compilation
 # paradigms, the Table I cost model, the 16k-layer dataset, the
 # 12-classifier zoo, and the fast-switching compiling system.
-from .hw import SpiNNaker2Config, TPUv5eConfig, DEFAULT_S2, DEFAULT_TPU
+from .hw import SpiNNaker2Config, DEFAULT_S2
 from .layer import (
     LayerCharacter,
     LIFParams,
@@ -41,7 +41,7 @@ from .switching import (
 )
 
 __all__ = [
-    "SpiNNaker2Config", "TPUv5eConfig", "DEFAULT_S2", "DEFAULT_TPU",
+    "SpiNNaker2Config", "DEFAULT_S2",
     "LayerCharacter", "LIFParams", "Population", "Projection",
     "SNNLayer", "SNNNetwork",
     "feedforward_network", "random_layer", "random_projection",

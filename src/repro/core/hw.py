@@ -1,15 +1,9 @@
 """Hardware models.
 
-Two hardware descriptions live here:
-
-* :class:`SpiNNaker2Config` — the paper's target. All byte budgets in the
-  Table I cost model and both paradigm compilers are driven by this object,
-  so the switching system stays bit-faithful to the paper while remaining
-  parameterizable (the paper itself changes DTCM 64 kB -> 96 kB vs sPyNNaker).
-
-* :class:`TPUv5eConfig` — the roofline target for the JAX/Pallas runtimes and
-  the LM substrate.  Constants from the task spec: 197 TFLOP/s bf16 per chip,
-  819 GB/s HBM, ~50 GB/s per ICI link.
+:class:`SpiNNaker2Config` is the paper's target. All byte budgets in the
+Table I cost model and both paradigm compilers are driven by this object,
+so the switching system stays bit-faithful to the paper while remaining
+parameterizable (the paper itself changes DTCM 64 kB -> 96 kB vs sPyNNaker).
 
 Plus the **aggregate per-core accounting** the placement engine packs
 against (:class:`PEBudget` / :class:`PEUsage`).  The paradigm compilers
@@ -62,23 +56,7 @@ class SpiNNaker2Config:
         return self.mac_rows * self.mac_cols
 
 
-@dataclasses.dataclass(frozen=True)
-class TPUv5eConfig:
-    """Roofline constants for one TPU v5e chip (task spec)."""
-
-    peak_flops_bf16: float = 197e12      # FLOP/s
-    hbm_bandwidth: float = 819e9         # B/s
-    ici_link_bandwidth: float = 50e9     # B/s per link
-    hbm_bytes: int = 16 * 1024**3        # 16 GiB HBM per chip
-    vmem_bytes: int = 128 * 1024**2      # ~128 MiB VMEM
-    mxu_dim: int = 128                   # systolic array tile edge
-
-    # int8 matmuls run at 2x bf16 on the MXU (v5e supports int8 @ ~394 TOPS).
-    peak_ops_int8: float = 394e12
-
-
 DEFAULT_S2 = SpiNNaker2Config()
-DEFAULT_TPU = TPUv5eConfig()
 
 
 class BudgetExceeded(ValueError):

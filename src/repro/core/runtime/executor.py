@@ -697,6 +697,76 @@ def _param_axes(meta: LayerMeta, form: str) -> Tuple[Tuple, ...]:
     return (("neurons", "cols"), ("cols",), ("cols",))
 
 
+def _event_operands(exe, i: int) -> Tuple[jnp.ndarray, ...]:
+    """The event form's operands: the lowered rows, on the device."""
+    axes = _param_axes(exe.metas[i], "event")
+    return tuple(
+        exe._place(jnp.asarray(a), ax) for a, ax in zip(exe.params[i], axes)
+    )
+
+
+def _spike_operands(exe, i: int) -> Tuple[jnp.ndarray, ...]:
+    """The spike-driven form's operands ``(row_len, rows)``."""
+    meta = exe.metas[i]
+    with tracing.span("lower.spike", rows=meta.n_rows,
+                      sources=meta.n_source):
+        row_len, rows = spike_serial_operands(exe._serial_exe(i), meta.spike)
+        return (jnp.asarray(row_len), jnp.asarray(rows))
+
+
+def _dense_operands(exe, i: int) -> Tuple[jnp.ndarray, ...]:
+    """The dense form's ``(d_slots, S, T)`` weights."""
+    w = jnp.asarray(dense_serial_weights(exe._serial_exe(i)))
+    return (exe._place(w, _param_axes(exe.metas[i], "dense")[0]),)
+
+
+def _sparse_operands(exe, i: int) -> Tuple[jnp.ndarray, ...]:
+    """The sparse form's ELL operands ``(ell_val, ell_idx)``."""
+    val, idx = sparse_serial_operands(exe._serial_exe(i))
+    axes = _param_axes(exe.metas[i], "sparse")
+    return (
+        exe._place(jnp.asarray(val), axes[0]),
+        exe._place(jnp.asarray(idx), axes[1]),
+    )
+
+
+def _temporal_operands(exe, i: int) -> Tuple[jnp.ndarray, ...]:
+    """A parallel projection's whole-train dense operand: the WDM stack
+    scattered back into the dense form's delay-stacked layout (integer
+    accumulation — exact)."""
+    meta = exe.metas[i]
+    wdm, col_src, col_dly = (np.asarray(a) for a in exe.params[i])
+    w_np = np.zeros(
+        (meta.delay_range + 1, meta.n_source, meta.n_target), np.float32,
+    )
+    np.add.at(w_np, (col_dly, col_src), wdm.T.astype(np.float32))
+    return (exe._place(jnp.asarray(w_np), (None, None, "neurons")),)
+
+
+#: Operand kind -> the function ``(exe, i)`` that makes projection
+#: ``i``'s device operands of that kind.  A form reads the kind of its own
+#: name unless :func:`_operand_kind` says otherwise, so a new form adds
+#: one entry.
+_OPERAND_MAKERS = {
+    "event": _event_operands,
+    "dense": _dense_operands,
+    "sparse": _sparse_operands,
+    "spike": _spike_operands,
+    "temporal": _temporal_operands,
+}
+
+
+def _operand_kind(meta: LayerMeta, form: str) -> str:
+    """The operand kind ``form`` reads.  ``temporal_sparse`` reads the
+    sparse form's ELL operands and a serial ``temporal`` projection the
+    dense form's weights, so no operand is held twice on the device."""
+    if form == "temporal_sparse":
+        return "sparse"
+    if form == "temporal" and meta.paradigm == "serial":
+        return "dense"
+    return form
+
+
 class NetworkExecutable:
     """A whole compiled application graph, lowered once, run in one scan."""
 
@@ -725,11 +795,7 @@ class NetworkExecutable:
         #: Crossover model deciding event vs dense serial form per batch.
         self.cost_model = cost_model or DEFAULT_SERIAL_BATCH_COST
         self._fns = {}       # (path, interpret, forms, max_iters) -> jit fn
-        self._dense = {}     # layer index -> (d_slots, S, T) dense operand
-        self._sparse = {}    # layer index -> (ell_val, ell_idx) ELL operands
-        self._temporal = {}  # layer index -> whole-train dense operand
-        self._event = {}     # layer index -> event-form device operands
-        self._spike = {}     # layer index -> (row_len, rows) spike operands
+        self._operands = {}  # (operand kind, layer index) -> device operands
         #: One ``(v0,)`` device operand per ``iaf_psc_exp`` population, in
         #: update order (set by :meth:`build`).
         self.neuron_params: Tuple[Tuple[jnp.ndarray, ...], ...] = ()
@@ -861,57 +927,6 @@ class NetworkExecutable:
             lif=LIFParams(alpha=meta.alpha, v_th=meta.v_th),
         )
 
-    def _event_param(self, i: int) -> Tuple[jnp.ndarray, ...]:
-        """The layer's event-form operands on the device, cached."""
-        ops = self._event.get(i)
-        if ops is None:
-            axes = _param_axes(self.metas[i], "event")
-            ops = tuple(
-                self._place(jnp.asarray(a), ax)
-                for a, ax in zip(self.params[i], axes)
-            )
-            self._event[i] = ops
-        return ops
-
-    def _spike_param(self, i: int) -> Tuple[jnp.ndarray, ...]:
-        """The layer's spike-driven operands ``(row_len, rows)``, built
-        once and cached."""
-        ops = self._spike.get(i)
-        if ops is None:
-            meta = self.metas[i]
-            with tracing.span("lower.spike", rows=meta.n_rows,
-                              sources=meta.n_source):
-                row_len, rows = spike_serial_operands(
-                    self._serial_exe(i), meta.spike
-                )
-                ops = (jnp.asarray(row_len), jnp.asarray(rows))
-            self._spike[i] = ops
-        return ops
-
-    def _dense_param(self, i: int) -> Tuple[jnp.ndarray, ...]:
-        """The layer's dense-form operand, built once and cached."""
-        w = self._dense.get(i)
-        if w is None:
-            meta = self.metas[i]
-            w = jnp.asarray(dense_serial_weights(self._serial_exe(i)))
-            w = self._place(w, _param_axes(meta, "dense")[0])
-            self._dense[i] = w
-        return (w,)
-
-    def _sparse_param(self, i: int) -> Tuple[jnp.ndarray, ...]:
-        """The layer's ELL (sparse-form) operands, built once and cached."""
-        ell = self._sparse.get(i)
-        if ell is None:
-            meta = self.metas[i]
-            val, idx = sparse_serial_operands(self._serial_exe(i))
-            axes = _param_axes(meta, "sparse")
-            ell = (
-                self._place(jnp.asarray(val), axes[0]),
-                self._place(jnp.asarray(idx), axes[1]),
-            )
-            self._sparse[i] = ell
-        return ell
-
     # -- temporal-parallel structure and forms -------------------------------
     def _weights_nonneg(self, i: int) -> bool:
         v = self._nonneg.get(i)
@@ -1002,38 +1017,21 @@ class NetworkExecutable:
                 )
         return tuple(forms)
 
-    def _temporal_param(self, i: int) -> Tuple[jnp.ndarray, ...]:
-        """The whole-train dense operand: the serial dense (d_slots, S, T)
-        weights verbatim, or the parallel WDM stack scattered back into
-        the same delay-stacked layout (integer accumulation — exact)."""
-        meta = self.metas[i]
-        if meta.paradigm == "serial":
-            return self._dense_param(i)
-        w = self._temporal.get(i)
-        if w is None:
-            wdm, col_src, col_dly = (np.asarray(a) for a in self.params[i])
-            w_np = np.zeros(
-                (meta.delay_range + 1, meta.n_source, meta.n_target),
-                np.float32,
-            )
-            np.add.at(w_np, (col_dly, col_src), wdm.T.astype(np.float32))
-            w = self._place(jnp.asarray(w_np), (None, None, "neurons"))
-            self._temporal[i] = w
-        return (w,)
+    def _operands_for(self, i: int, form: str) -> Tuple[jnp.ndarray, ...]:
+        """Projection ``i``'s device operands for ``form``, built on first
+        use and cached by operand kind."""
+        key = (_operand_kind(self.metas[i], form), i)
+        ops = self._operands.get(key)
+        if ops is None:
+            ops = self._operands[key] = _OPERAND_MAKERS[key[0]](self, i)
+        return ops
 
     def _params_for(self, forms: Tuple[str, ...]) -> List[Tuple]:
-        """Each projection's operands for its form, then the neuron
+        """Each projection's operands for its form (a parallel step-scan
+        projection, ``"-"``, reads its lowered arrays), then the neuron
         operands of the ``iaf_psc_exp`` populations."""
-        per_form = {
-            "event": self._event_param,
-            "dense": self._dense_param,
-            "sparse": self._sparse_param,
-            "spike": self._spike_param,
-            "temporal": self._temporal_param,
-            "temporal_sparse": self._sparse_param,
-        }
         return [
-            per_form[form](i) if form in per_form else p
+            p if form == "-" else self._operands_for(i, form)
             for i, (form, p) in enumerate(zip(forms, self.params))
         ] + list(self.neuron_params)
 
@@ -1086,40 +1084,24 @@ class NetworkExecutable:
             ]
             self._mesh = None      # device pinning replaces mesh placement
             self._rules = None
-            self._dense.clear()
-            self._sparse.clear()
-            self._temporal.clear()
-            self._event.clear()
-            self._spike.clear()
-            self._fns.clear()
             if self.report is not None:
                 self.report.placement = assignment
-            return self
-        mesh = shardlib.snn_mesh() if mesh is None else mesh
-        self._rules = rules or shardlib.snn_rules()
-        self._mesh = mesh
-        if mesh is None:
-            return self
-        from jax.sharding import NamedSharding
-
-        def place(arr, axes):
-            spec = shardlib.spec_for_shape(axes, self._rules, arr.shape, mesh)
-            return jax.device_put(arr, NamedSharding(mesh, spec))
-
-        self.params = [
-            tuple(
-                place(arr, ax)
-                for arr, ax in zip(p, _param_axes(meta, "event"))
-            )
-            for meta, p in zip(self.metas, self.params)
-        ]
-        # dense/sparse/temporal operands and jitted entries were traced/
-        # placed against the old layout; rebuild all lazily
-        self._dense.clear()
-        self._sparse.clear()
-        self._temporal.clear()
-        self._event.clear()
-        self._spike.clear()
+        else:
+            mesh = shardlib.snn_mesh() if mesh is None else mesh
+            self._rules = rules or shardlib.snn_rules()
+            self._mesh = mesh
+            if mesh is None:
+                return self
+            self.params = [
+                tuple(
+                    self._place(arr, ax)
+                    for arr, ax in zip(p, _param_axes(meta, "event"))
+                )
+                for meta, p in zip(self.metas, self.params)
+            ]
+        # every form's operands and the jitted entries were placed and
+        # traced against the old layout; rebuild them lazily
+        self._operands.clear()
         self._fns.clear()
         return self
 

@@ -1,9 +1,2 @@
-from .compat import compat_shard_map
-from .sharding import (
-    constrain, make_rules, placement_put, sharding_ctx, snn_mesh, snn_rules,
-    spec_for, spec_for_shape, tree_shardings,
-)
-from .fault_tolerance import (
-    FaultTolerantDriver, HeartbeatRegistry, HostFailure, RestartPolicy,
-    StragglerDetector, plan_elastic_mesh,
-)
+from .sharding import placement_put, snn_mesh, snn_rules, spec_for_shape
+from .fault_tolerance import HeartbeatRegistry, RestartPolicy, StragglerDetector

@@ -1,56 +1,17 @@
 """Logical-axis sharding rules -> concrete PartitionSpecs.
 
-MaxText-style indirection: every parameter/activation dimension carries a
-*logical* name (assigned in ``repro.models.init.param_specs``); a rules
-table maps logical names to mesh axes.  Swapping the rules table is how the
-§Perf hillclimb changes sharding without touching model code.
-
-Default rules (single pod 16x16 / multi-pod 2x16x16):
-
-    batch      -> ("pod", "data")     # DP over pods and the data axis
-    vocab      -> "model"             # TP of embeddings / logits
-    heads      -> "model"             # TP of attention + all projections
-    mlp        -> "model"             # TP of FFN hidden
-    expert     -> "model"             # EP: experts across the model axis
-    embed      -> "data" iff cfg.fsdp # FSDP: shard the d_model dim of params
-    seq        -> None                # (SP variant used by the hillclimb)
-    layers     -> None                # scan axis is never sharded
+Every operand dimension of the SNN runtime carries a *logical* name
+(``executor._param_axes``); a rules table (:func:`snn_rules`) maps
+logical names to the axes of a ``("data", "model")`` mesh, and
+:func:`spec_for_shape` turns a shape's names into a ``PartitionSpec``
+that divides it.
 """
 from __future__ import annotations
 
-import contextlib
-import threading
 from typing import Optional
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-
-def make_rules(*, fsdp: bool = False, multi_pod: bool = False,
-               seq_axis: Optional[str] = None,
-               kv_seq_shard: bool = False) -> dict:
-    batch = ("pod", "data") if multi_pod else ("data",)
-    return {
-        "batch": batch,
-        "vocab": ("model",),
-        "heads": ("model",),
-        "mlp": ("model",),
-        "expert": ("model",),
-        "expert_ff": (),
-        "embed": ("data",) if fsdp else (),
-        "seq": (seq_axis,) if seq_axis else (),
-        # decode-cache sequence axis: sharding it over "model" is the
-        # flash-decoding split-K layout (§Perf lever H6) — the natural TP
-        # axis when kv_heads < model size (GQA caches)
-        "kv_seq": ("model",) if kv_seq_shard else (),
-        "layers": (),
-        None: (),
-    }
-
-
-# The version shim lives in .compat; re-exported here because call sites
-# historically imported it from this module.
-from .compat import compat_shard_map  # noqa: F401
+from jax.sharding import Mesh, PartitionSpec as P
 
 
 def placement_put(arr, device_index: int):
@@ -126,27 +87,12 @@ def snn_mesh(devices=None, *, model_axis: int = 1) -> Optional[Mesh]:
     return Mesh(grid, ("data", "model"))
 
 
-def spec_for(axes, rules) -> P:
-    """axes: tuple of logical names (or None) per dim -> PartitionSpec."""
-    parts = []
-    for a in axes:
-        mesh_axes = rules.get(a, ())
-        mesh_axes = tuple(m for m in mesh_axes if m is not None)
-        if len(mesh_axes) == 0:
-            parts.append(None)
-        elif len(mesh_axes) == 1:
-            parts.append(mesh_axes[0])
-        else:
-            parts.append(mesh_axes)
-    return P(*parts)
-
-
 def _fit_axes(mesh_axes, dim: int, mesh: Mesh):
     """Longest prefix of mesh axes whose size product divides ``dim``.
 
-    jit argument shardings must divide the dimension exactly; logical rules
-    that do not divide a given tensor (kv=1 heads, odd fused projections,
-    batch=1 decode) degrade to replication on the offending axes.
+    jit argument shardings must divide the dimension exactly; a rule that
+    does not divide a given tensor (a 20-neuron population over a model
+    axis of 8, a batch of 1) degrades to replication.
     """
     axes = tuple(m for m in mesh_axes if m is not None)
     while axes:
@@ -160,59 +106,15 @@ def _fit_axes(mesh_axes, dim: int, mesh: Mesh):
 
 
 def spec_for_shape(axes, rules, shape, mesh: Mesh) -> P:
+    """The ``PartitionSpec`` of a ``shape`` whose dimensions carry the
+    logical ``axes``: each rule that divides its dimension, and whose
+    mesh axes no earlier dimension took, shards it; any other dimension
+    is replicated."""
     parts = []
     used = set()  # a mesh axis may appear at most once per spec
     for dim, a in zip(shape, axes):
-        rule = tuple(m for m in rules.get(a, ()) if m is not None)
-        fit = _fit_axes(rule, int(dim), mesh)
+        fit = _fit_axes(rules.get(a, ()), int(dim), mesh)
         fit = tuple(m for m in fit if m not in used)
         used.update(fit)
-        if len(fit) == 0:
-            parts.append(None)
-        elif len(rule) == 1:
-            # single-axis rules read as bare names ("data"); multi-axis
-            # rules keep tuple form even when only a prefix fits, so a
-            # degraded ("pod", "data") -> ("pod",) stays visibly a prefix
-            parts.append(fit[0])
-        else:
-            parts.append(fit)
+        parts.append(fit[0] if len(fit) == 1 else (fit or None))
     return P(*parts)
-
-
-def tree_shardings(spec_tree, shape_tree, mesh: Mesh, rules: dict):
-    """Map trees of (logical-axis tuples, ShapeDtypeStructs) to NamedShardings."""
-    return jax.tree.map(
-        lambda axes, sds: NamedSharding(
-            mesh, spec_for_shape(axes, rules, sds.shape, mesh)
-        ),
-        spec_tree,
-        shape_tree,
-        is_leaf=lambda x: isinstance(x, tuple),
-    )
-
-
-# --- activation constraint context ------------------------------------------
-
-_ctx = threading.local()
-
-
-@contextlib.contextmanager
-def sharding_ctx(mesh: Mesh, rules: dict):
-    """While active, ``constrain`` applies with_sharding_constraint."""
-    prev = getattr(_ctx, "v", None)
-    _ctx.v = (mesh, rules)
-    try:
-        yield
-    finally:
-        _ctx.v = prev
-
-
-def constrain(x, axes):
-    """Constrain activation ``x`` to the logical ``axes`` if a ctx is active."""
-    ctx = getattr(_ctx, "v", None)
-    if ctx is None:
-        return x
-    mesh, rules = ctx
-    return jax.lax.with_sharding_constraint(
-        x, NamedSharding(mesh, spec_for_shape(axes, rules, x.shape, mesh))
-    )

@@ -1,8 +1,7 @@
 """Pallas TPU kernel: chunked affine membrane scan.
 
-Same state-space-duality move as ``kernels/ssd_chunk``: within a chunk of
-Q timesteps the reset-free recurrence ``v[t] = alpha*v[t-1] + c[t]``
-equals a lower-triangular matmul
+A state-space-duality move: within a chunk of Q timesteps the reset-free
+recurrence ``v[t] = alpha*v[t-1] + c[t]`` equals a lower-triangular matmul
 
     v = L @ c + alpha^(i+1) * v_carry,    L[i, j] = alpha^(i-j)  (i >= j)
 

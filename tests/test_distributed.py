@@ -1,92 +1,74 @@
-"""Sharding rules, checkpointing, fault tolerance, optimizer, compression."""
-import os
-
-import jax
-import jax.numpy as jnp
-import numpy as np
+"""Sharding rules and the fault-tolerance primitives serving wires in."""
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.checkpoint import CheckpointManager
-from repro.distributed import (
-    FaultTolerantDriver, HeartbeatRegistry, HostFailure, RestartPolicy,
-    StragglerDetector, plan_elastic_mesh,
-)
-from repro.distributed.sharding import make_rules, spec_for_shape
-from repro.optim import (
-    AdamWConfig, apply_updates, init_state, psum_compressed, schedule,
-)
+from repro.core.runtime.executor import LayerMeta, _param_axes
+from repro.distributed import HeartbeatRegistry, StragglerDetector
+from repro.distributed.sharding import snn_rules, spec_for_shape
 
 
 class FakeMesh:
-    shape = {"pod": 2, "data": 16, "model": 16}
+    shape = {"data": 2, "model": 4}
+
+
+def _meta(paradigm):
+    return LayerMeta(paradigm=paradigm, n_source=8, n_target=8,
+                     delay_range=1, alpha=0.5, v_th=1.0, n_rows=8)
 
 
 class TestShardingRules:
     def test_divisible_dims_shard(self):
-        rules = make_rules(multi_pod=True)
-        spec = spec_for_shape(("batch", None), rules, (256, 4096), FakeMesh())
-        assert spec == P(("pod", "data"), None)
-
-    def test_non_divisible_dims_degrade(self):
-        rules = make_rules(multi_pod=True)
-        # kv=1 heads cannot shard over model=16 -> replicated
-        spec = spec_for_shape(
-            ("layers", "batch", None, "heads", None), rules,
-            (8, 128, 2048, 1, 256), FakeMesh(),
-        )
-        assert spec == P(None, ("pod", "data"), None, None, None)
-        # 3352 % 16 != 0 -> replicated
-        spec = spec_for_shape(("layers", None, "heads"), rules,
-                              (24, 768, 3352), FakeMesh())
-        assert spec[2] is None
-
-    def test_batch_prefix_fit(self):
-        rules = make_rules(multi_pod=True)
-        # batch=2 divides pod(2) but not pod*data(32): keep the prefix
-        spec = spec_for_shape(("batch",), rules, (2,), FakeMesh())
-        assert spec == P(("pod",))
-
-    def test_fsdp_rule(self):
-        rules = make_rules(fsdp=True)
-        spec = spec_for_shape(("embed", "heads"), rules, (4096, 4096), FakeMesh())
+        spec = spec_for_shape(("batch", "neurons"), snn_rules(), (8, 20),
+                              FakeMesh())
         assert spec == P("data", "model")
 
+    def test_non_divisible_dims_degrade(self):
+        # batch 3 over data=2 and 6 neurons over model=4 -> replicated
+        spec = spec_for_shape(("batch", "neurons"), snn_rules(), (3, 6),
+                              FakeMesh())
+        assert spec == P(None, None)
 
-class TestCheckpoint:
-    def test_roundtrip_and_gc(self, tmp_path):
-        mgr = CheckpointManager(str(tmp_path), keep=2, async_write=False)
-        tree = {"a": jnp.arange(6, dtype=jnp.float32).reshape(2, 3),
-                "b": [jnp.ones((4,), jnp.bfloat16), jnp.zeros((), jnp.int32)]}
-        for step in (10, 20, 30):
-            mgr.save(step, jax.tree.map(lambda x: x + step, tree))
-        assert mgr.list_steps() == [20, 30]  # keep=2
-        restored = mgr.restore(tree, 30)
-        np.testing.assert_allclose(
-            np.asarray(restored["a"], np.float32),
-            np.asarray(tree["a"]) + 30,
-        )
-        assert restored["b"][0].dtype == jnp.bfloat16
+    def test_batch_prefix_fit(self):
+        # neurons and rows both map to "model": only the first gets it
+        spec = spec_for_shape(("neurons", "rows"), snn_rules(), (8, 8),
+                              FakeMesh())
+        assert spec == P("model", None)
 
-    def test_async_write(self, tmp_path):
-        mgr = CheckpointManager(str(tmp_path), async_write=True)
-        mgr.save(5, {"x": jnp.ones((8, 8))})
-        mgr.wait()
-        assert mgr.latest_step() == 5
 
-    def test_driver_restores_after_failure(self, tmp_path):
-        mgr = CheckpointManager(str(tmp_path), async_write=False)
-        calls = {"n": 0}
+# Every operand of ``_param_axes`` at a width the mesh divides and at one
+# it does not: (paradigm, form, operand index, shape, expected spec).
+_OPERAND_CASES = [
+    ("serial", "event", 0, (64,), P("model")),         # row_weight
+    ("serial", "event", 1, (66,), P(None)),            # row_delay
+    ("serial", "event", 2, (12,), P("model")),         # row_src
+    ("serial", "event", 3, (2,), P(None)),             # row_tgt
+    ("serial", "dense", 0, (2, 2048, 20), P(None, None, "model")),
+    ("serial", "dense", 0, (2, 20, 6), P(None, None, None)),
+    ("serial", "sparse", 0, (40, 7), P("model", None)),  # ell_val
+    ("serial", "sparse", 1, (42, 7), P(None, None)),     # ell_idx
+    ("parallel", "-", 0, (20, 4096), P("model", None)),  # wdm_stack
+    ("parallel", "-", 1, (4096,), P(None)),              # col_source
+    ("parallel", "-", 2, (4096,), P(None)),              # col_delay
+]
 
-        def step_fn(state, step):
-            calls["n"] += 1
-            if step == 7 and calls["n"] == 8:  # fail once at step 7
-                raise HostFailure("boom")
-            return {"v": state["v"] + 1}
 
-        drv = FaultTolerantDriver(mgr, RestartPolicy(max_retries=2), ckpt_every=5)
-        out = drv.run({"v": np.zeros(3)}, step_fn, steps=10)
-        np.testing.assert_allclose(out["v"], 10)  # exactly 10 effective steps
+@pytest.mark.parametrize("paradigm,form,k,shape,want", _OPERAND_CASES)
+def test_operand_axes_place(paradigm, form, k, shape, want):
+    axes = _param_axes(_meta(paradigm), form)[k]
+    assert len(axes) == len(shape)
+    assert spec_for_shape(axes, snn_rules(), shape, FakeMesh()) == want
+
+
+@pytest.mark.parametrize("axes,shape,want", [
+    (("steps", "batch", None), (256, 8, 2048), P(None, "data", None)),
+    (("steps", "batch", None), (256, 3, 2048), P(None, None, None)),
+    (("batch",), (8,), P("data")),
+    (("batch",), (1,), P(None)),
+])
+def test_input_axes_place(axes, shape, want):
+    """The request batch's placement (``_place_inputs``): the batch on
+    the data axis where it divides, the steps never sharded."""
+    assert spec_for_shape(axes, snn_rules(), shape, FakeMesh()) == want
 
 
 class TestFaultTolerance:
@@ -103,48 +85,3 @@ class TestFaultTolerance:
             for _ in range(5):
                 det.record(h, 1.0 if h != 3 else 2.5)
         assert det.stragglers() == [3]
-
-    def test_elastic_mesh_shrink(self):
-        shape, axes = plan_elastic_mesh(512, model_parallel=16)
-        assert shape == (2, 16, 16) and axes == ("pod", "data", "model")
-        shape, axes = plan_elastic_mesh(496, model_parallel=16)  # lost a host
-        assert shape == (31, 16) and axes == ("data", "model")
-        with pytest.raises(RuntimeError):
-            plan_elastic_mesh(8, model_parallel=16)
-
-
-class TestOptim:
-    def test_adamw_descends_quadratic(self):
-        params = {"w": jnp.asarray([5.0, -3.0])}
-        opt = init_state(params)
-        cfg = AdamWConfig(lr=0.1, weight_decay=0.0, warmup_steps=1,
-                          total_steps=200)
-        loss = lambda p: jnp.sum(p["w"] ** 2)
-        for _ in range(150):
-            g = jax.grad(loss)(params)
-            params, opt, _ = apply_updates(params, g, opt, cfg)
-        assert float(loss(params)) < 1e-2
-
-    def test_schedule_endpoints(self):
-        cfg = AdamWConfig(lr=1.0, warmup_steps=10, total_steps=100,
-                          min_lr_ratio=0.1)
-        assert float(schedule(cfg, 0)) == pytest.approx(0.1, abs=0.02)
-        assert float(schedule(cfg, 9)) == pytest.approx(1.0, abs=0.01)
-        assert float(schedule(cfg, 100)) == pytest.approx(0.1, abs=0.01)
-
-    def test_psum_compressed_single_device(self):
-        mesh = jax.make_mesh((1,), ("data",))
-        g = {"w": jnp.asarray(np.random.default_rng(0).normal(size=64),
-                              jnp.float32)}
-
-        def f(g):
-            return psum_compressed(g, "data")
-
-        from repro.distributed.compat import compat_shard_map
-
-        out = jax.jit(
-            compat_shard_map(f, mesh=mesh, in_specs=(P(),), out_specs=P())
-        )(g)
-        np.testing.assert_allclose(
-            np.asarray(out["w"]), np.asarray(g["w"]), atol=np.abs(g["w"]).max() / 100
-        )

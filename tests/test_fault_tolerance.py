@@ -1,9 +1,9 @@
 """Unit coverage for the distributed fault-tolerance primitives.
 
 These are the pure-bookkeeping pieces the serving supervisor wires to
-real signals (`HeartbeatRegistry`, `StragglerDetector`, `RestartPolicy`)
-plus the elastic re-mesh planner — all injectable-clock / pure-function,
-so they test deterministically on one host.
+real signals (`HeartbeatRegistry`, `StragglerDetector`, `RestartPolicy`),
+all injectable-clock / pure-function, so they test deterministically on
+one host.
 """
 import pytest
 
@@ -11,7 +11,6 @@ from repro.distributed.fault_tolerance import (
     HeartbeatRegistry,
     RestartPolicy,
     StragglerDetector,
-    plan_elastic_mesh,
 )
 
 
@@ -78,37 +77,3 @@ def test_restart_policy_retry_budget_boundary():
     pol = RestartPolicy(max_retries=2)
     assert pol.should_restart(0) and pol.should_restart(1)
     assert not pol.should_restart(2)    # attempt == max_retries: stop
-
-
-# -- elastic re-mesh ---------------------------------------------------------
-
-def test_plan_elastic_mesh_raises_when_model_group_impossible():
-    with pytest.raises(RuntimeError, match="cannot form"):
-        plan_elastic_mesh(3, model_parallel=4)
-
-
-def test_plan_elastic_mesh_keeps_pod_axis_when_divisible():
-    shape, names = plan_elastic_mesh(32, model_parallel=4, pods_preferred=2)
-    assert names == ("pod", "data", "model")
-    assert shape == (2, 4, 4)
-
-
-def test_plan_elastic_mesh_drops_pod_axis_for_small_survivor_sets():
-    # 3 groups of 4: not divisible by 2 pods -> 2-axis mesh
-    shape, names = plan_elastic_mesh(12, model_parallel=4, pods_preferred=2)
-    assert names == ("data", "model")
-    assert shape == (3, 4)
-    # 4 groups but < 2*pods_preferred per pod requirement boundary:
-    shape, names = plan_elastic_mesh(8, model_parallel=4, pods_preferred=2)
-    assert names == ("data", "model") and shape == (2, 4)
-
-
-def test_plan_elastic_mesh_model_axis_always_intact():
-    for chips in (4, 5, 7, 16, 33):
-        shape, names = plan_elastic_mesh(chips, model_parallel=4)
-        assert shape[names.index("model")] == 4
-        # never plans more chips than survive
-        total = 1
-        for d in shape:
-            total *= d
-        assert total <= chips

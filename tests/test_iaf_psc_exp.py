@@ -154,3 +154,67 @@ def test_population_lif_may_be_either_model():
     assert IafPscExpParams() == IafPscExpParams(v0=np.ones(3))
     assert hash(IafPscExpParams()) == hash(IafPscExpParams(v0=np.ones(3)))
     assert lif != IafPscExpParams()
+
+
+#: Potjans & Diesmann (2014) population sizes, L2/3E ... L6I, and the DC
+#: background each receives (K_ext x 8 Hz x 87.8 pA x 0.5 ms).
+PD14 = [(20683, 1600), (5834, 1500), (21915, 2100), (5479, 1900),
+        (4850, 2000), (1065, 1900), (14395, 2900), (2948, 2100)]
+
+
+@pytest.mark.parametrize("batch,n,k_ext", [(1, n, k) for n, k in PD14]
+                         + [(3, 1065, 1900), (5, 301, 2100)])
+def test_kernel_first_step_from_v0_equals_numpy_int64(batch, n, k_ext):
+    """One step of the kernel body from a population's initial state
+    (NEST's V0 ~ N(-58, 10) mV, no current, not refractory) with this
+    step's synaptic input, laid out as the executor lays it out."""
+    rng = np.random.default_rng(n)
+    params = IafPscExpParams(weight_pa=87.8, i_e=k_ext * 8 * 87.8 * 0.5e-3,
+                             v0=rng.normal(-58.0, 10.0, n))
+    consts = params.fixed()
+    rows = iaf_layout(batch, n)
+
+    def lay(a):
+        flat = np.zeros(rows * 128, np.int32)
+        flat[: a.size] = a.reshape(-1)
+        return flat.reshape(rows, 128)
+
+    v = lay(np.tile(params.v0_fixed(n), (batch, 1)))
+    i_in = lay(rng.integers(-60, 120, (batch, n)))
+    zero = np.zeros((rows, 128), np.int32)
+    args = (i_in, v, zero, zero)
+    want = iaf_psc_exp_int64(*args, consts)
+    got = iaf_psc_exp(*map(jnp.asarray, args), consts=consts, interpret=True)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), w)
+    z = np.asarray(want[3]).reshape(-1)[: batch * n]
+    assert z.any() and not z.all()        # V0 straddles the threshold
+
+
+@pytest.mark.parametrize("batch,delay_back", [(1, 0), (3, 2)])
+@pytest.mark.parametrize("form", ["event", "sparse", "dense", "spike"])
+def test_iaf_network_each_serial_form(form, batch, delay_back):
+    net = _loop_net(delay_back)
+    report = SwitchingCompiler("serial").compile_network(net)
+    exe = network_executable(net, report)
+    x = (np.random.default_rng(batch).random((50, batch, 5)) < 0.3
+         ).astype(np.float32)
+    want = run_graph_reference(net, x)
+    got = exe.run(x, serial_form=form)
+    assert report.serial_forms[("fused", batch)] == (form,) * 3
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+    assert all(w.sum() > 0 for w in want)
+
+
+@pytest.mark.parametrize("batch", [1, 3])
+def test_iaf_network_parallel_with_a_delayed_back_edge(batch):
+    net = _loop_net(2)
+    report = SwitchingCompiler("parallel").compile_network(net)
+    exe = network_executable(net, report)
+    x = (np.random.default_rng(batch).random((50, batch, 5)) < 0.3
+         ).astype(np.float32)
+    want = run_graph_reference(net, x)
+    for g, w in zip(exe.run(x), want):
+        np.testing.assert_array_equal(g, w)
+    assert all(w.sum() > 0 for w in want)

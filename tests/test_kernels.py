@@ -85,6 +85,34 @@ class TestSpikeWDMMatmul:
         row_blocks, cols = -(-m // bm), -(-k // bk) * bk
         assert row_blocks * cols * bn <= m * k / 8
 
+    @pytest.mark.parametrize("m,k,tiles", [
+        (14395, 32744, (1824, 2048, 128)),    # TH -> L6E
+        (2948, 131316, (1504, 2688, 128)),    # L5E -> L6I
+        (5479, 30681, (1856, 2048, 128)),     # TH -> L4I
+        (1065, 122480, (1065, 3840, 128)),    # L5E -> L5I
+        (1065, 111189, (1065, 3840, 128)),    # L6E -> L5I
+        (2948, 22021, (1504, 2560, 128)),     # TH -> L6I
+        (2948, 7633, (1504, 2560, 128)),      # L4I -> L6I
+    ])
+    def test_microcircuit_tiles(self, m, k, tiles):
+        """The blocks the microcircuit's 7 parallel maps run with at
+        batch 1 (timed on the chip with exactly these tiles)."""
+        assert wdm_tiles(m, k, 1) == tiles
+
+    def test_gesture_parallel_layer_is_one_block(self):
+        """The gesture network's parallel layer (2048 -> 20, delay 1) at
+        batch 8: the whole map and every spike lane in one block."""
+        from repro.core import SwitchingCompiler, feedforward_network
+        from repro.core.runtime.executor import get_layer_executable
+
+        layer = feedforward_network([2048, 20, 4], 0.0316, 1).layers[0]
+        exe = get_layer_executable(
+            SwitchingCompiler("parallel").compile_layer(layer), layer.lif
+        )
+        m, k = exe.wdm_stack.shape
+        assert (m, k) == (20, 965)
+        assert wdm_tiles(m, k, 8) == (20, 1024, 128)
+
     @pytest.mark.parametrize("bm,bn,bk", [(128, 128, 128), (128, 128, 512)])
     def test_block_shapes(self, bm, bn, bk):
         a, x = rand_wdm(256, 1024), rand_spikes(1024, 256)
@@ -114,50 +142,6 @@ class TestLIFUpdate:
         vn, zn = lif_update(i, v, z, alpha=0.5, v_th=64.0, interpret=True)
         assert float(vn[0, 0]) == 100.0 and float(zn[0, 0]) == 1.0
         assert float(vn[1, 0]) == 0.0 and float(zn[1, 0]) == 0.0
-
-
-class TestSSDChunk:
-    """Mamba-2 SSD intra-chunk kernel vs pure-jnp oracle."""
-
-    @pytest.mark.parametrize("q,h,p,n", [
-        (256, 24, 64, 128),   # mamba2-130m production chunk
-        (64, 3, 16, 32),      # small odd-ish
-        (16, 1, 8, 8),        # tiny
-        (128, 5, 32, 64),
-    ])
-    def test_matches_ref(self, q, h, p, n):
-        from repro.kernels.ssd_chunk import ssd_chunk, ssd_chunk_ref
-        x = jnp.asarray(RNG.normal(size=(q, h, p)), jnp.float32)
-        b = jnp.asarray(RNG.normal(size=(q, h, n)), jnp.float32)
-        c = jnp.asarray(RNG.normal(size=(q, h, n)), jnp.float32)
-        la = jnp.asarray(-np.abs(RNG.normal(size=(q, h)) * 0.1), jnp.float32)
-        y, s = ssd_chunk(x, b, c, la, interpret=True)
-        yr, sr = ssd_chunk_ref(x, b, c, la)
-        np.testing.assert_allclose(np.asarray(y), np.asarray(yr),
-                                   rtol=1e-4, atol=1e-4)
-        np.testing.assert_allclose(np.asarray(s), np.asarray(sr),
-                                   rtol=1e-4, atol=1e-4)
-
-    def test_matches_model_ssd_math(self):
-        """The kernel's chunk semantics == the mamba2 block's inline SSD
-        (sequential-scan cross-check on a single chunk)."""
-        from repro.kernels.ssd_chunk import ssd_chunk_ref
-        q, h, p, n = 12, 2, 4, 6
-        x = np.asarray(RNG.normal(size=(q, h, p)), np.float32)
-        b = np.asarray(RNG.normal(size=(q, h, n)), np.float32)
-        c = np.asarray(RNG.normal(size=(q, h, n)), np.float32)
-        la = -np.abs(np.asarray(RNG.normal(size=(q, h)), np.float32) * 0.1)
-        # sequential recurrence oracle: s_t = exp(la_t) s_{t-1} + b_t x_t^T
-        y_seq = np.zeros((q, h, p), np.float32)
-        s = np.zeros((h, n, p), np.float32)
-        for t in range(q):
-            for hh in range(h):
-                s[hh] = np.exp(la[t, hh]) * s[hh] + np.outer(b[t, hh], x[t, hh])
-                y_seq[t, hh] = c[t, hh] @ s[hh]
-        y, state = ssd_chunk_ref(jnp.asarray(x), jnp.asarray(b),
-                                 jnp.asarray(c), jnp.asarray(la))
-        np.testing.assert_allclose(np.asarray(y), y_seq, rtol=1e-4, atol=1e-4)
-        np.testing.assert_allclose(np.asarray(state), s, rtol=1e-4, atol=1e-4)
 
 
 class TestDispatch:

@@ -16,7 +16,6 @@ from repro.core.cost_model import equal_parts
 from repro.core.layer import LIFParams
 from repro.core.runtime import run_parallel, run_reference, run_serial
 from repro.core.serial_compiler import pack_rows, unpack_rows
-from repro.optim.compression import dequantize, quantize
 
 SLOW = settings(
     max_examples=20, deadline=None,
@@ -101,61 +100,3 @@ def test_runtime_equivalence_property(ns, nt, dens, dr, gran, seed):
     z_ref = run_reference(layer, spikes, lif)
     np.testing.assert_array_equal(z_ref, run_serial(layer, spikes, lif))
     np.testing.assert_array_equal(z_ref, run_parallel(layer, spikes, lif))
-
-
-@given(st.lists(st.floats(-1e4, 1e4, allow_nan=False), min_size=1, max_size=256))
-@settings(max_examples=100, deadline=None)
-def test_int8_compression_error_bound(xs):
-    import jax.numpy as jnp
-    g = jnp.asarray(np.asarray(xs, np.float32))
-    c = quantize(g)
-    err = np.abs(np.asarray(dequantize(c) - g))
-    amax = float(np.max(np.abs(np.asarray(g))))
-    assert err.max() <= amax / 127.0 * 0.5 + 1e-6
-
-
-def test_attention_causality():
-    """Perturbing future tokens must not change past logits (all archs with
-    attention; exercises the Q-block streaming mask)."""
-    import dataclasses
-    import jax
-    import jax.numpy as jnp
-    from repro.configs import smoke_config
-    from repro.models import init as minit, model as M
-
-    for arch in ("qwen3-8b", "recurrentgemma-2b", "mamba2-130m"):
-        cfg = smoke_config(arch)
-        if cfg.moe is not None:
-            cfg = dataclasses.replace(
-                cfg, moe=dataclasses.replace(cfg.moe, capacity_factor=8.0))
-        params = minit.init_params(cfg, jax.random.PRNGKey(0))
-        rng = np.random.default_rng(0)
-        toks = rng.integers(0, cfg.vocab, (1, 16))
-        toks2 = toks.copy()
-        toks2[:, 10:] = rng.integers(0, cfg.vocab, (1, 6))
-        l1, _ = M.prefill(params, cfg, {"tokens": jnp.asarray(toks, jnp.int32)}, 16)
-        # compare hidden logits at position 9 via full forward
-        def logits_at(t):
-            batch = {"tokens": jnp.asarray(t[:, :10], jnp.int32)}
-            l, _ = M.prefill(params, cfg, batch, 16)
-            return np.asarray(l)
-        np.testing.assert_allclose(logits_at(toks), logits_at(toks2),
-                                   rtol=1e-5, err_msg=arch)
-
-
-def test_local_attention_window_equivalence():
-    """With window >= seq_len, windowed attention == full attention."""
-    import dataclasses
-    import jax
-    import jax.numpy as jnp
-    from repro.configs import smoke_config
-    from repro.models import init as minit, model as M
-
-    cfg = smoke_config("qwen3-8b")
-    params = minit.init_params(cfg, jax.random.PRNGKey(0))
-    rng = np.random.default_rng(1)
-    batch = {"tokens": jnp.asarray(rng.integers(0, cfg.vocab, (2, 12)), jnp.int32)}
-    full = M.train_loss(params, cfg, batch)
-    windowed = M.train_loss(
-        params, dataclasses.replace(cfg, attn_window=64), batch)
-    np.testing.assert_allclose(float(full), float(windowed), rtol=1e-6)

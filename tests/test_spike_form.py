@@ -9,6 +9,7 @@
 * the choosing policies (classifier, ideal) send what the parallel
   paradigm cannot hold to serial without a choice.
 """
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -19,7 +20,7 @@ from repro.core.layer import (
     SNNNetwork,
     SparseProjection,
 )
-from repro.core.runtime import network_executable, serial_runtime
+from repro.core.runtime import executor, network_executable, serial_runtime
 from repro.core.runtime.executor import NetworkExecutable
 from repro.core.runtime.reference import run_graph_reference
 from repro.core.switching import CompileReport, parallel_can_hold
@@ -248,3 +249,38 @@ def test_classifier_policy_sends_what_parallel_cannot_hold_to_serial():
         assert compiled.paradigm == "serial" and compiled.n_compilations == 1
     with pytest.raises(ValueError, match="int8"):
         SwitchingCompiler("parallel").compile_layer(over)
+
+
+# -- counter digits ----------------------------------------------------------
+
+def _digits(totals):
+    """int64 totals (..., n) as the launch's (..., n, 2) int32 digits."""
+    t = np.asarray(totals, np.int64)
+    return np.stack([t & (2**24 - 1), t >> 24], axis=-1).astype(np.int32)
+
+
+@pytest.mark.parametrize("start,steps", [
+    ([2**24 - 1, 0, 5], [[1, 0, 0]]),                  # carry into hi
+    ([2**24 - 3, 2**24 - 1, 0], [[2, 1, 0], [2, 1, 7]]),
+    ([0, 0, 0], [[2**23, 2**22, 1]] * 300),            # past 2**31
+    ([2**31 - 1, 2**32 + 5, 0], [[2**24 - 1, 1, 2**24]]),
+])
+def test_count_add_carries_exactly(start, steps):
+    acc = jnp.asarray(_digits(start))
+    for s in steps:
+        acc = executor._count_add(acc, jnp.asarray(s, jnp.int32))
+    a = np.asarray(acc)
+    assert (a[:, 0] >= 0).all() and (a[:, 0] < 2**24).all()
+    want = np.asarray(start, np.int64) + np.asarray(steps, np.int64).sum(0)
+    got = executor.read_counters(a)
+    assert list(got) == list(executor.LAUNCH_COUNTERS)
+    assert list(got.values()) == want.tolist()
+
+
+@pytest.mark.parametrize("lead", [(5,), (2, 3)])
+def test_read_counters_sums_leading_lane_axes(lead):
+    rng = np.random.default_rng(len(lead))
+    totals = rng.integers(0, 2**33, lead + (3,))
+    got = executor.read_counters(jnp.asarray(_digits(totals)))
+    assert list(got.values()) == totals.reshape(-1, 3).sum(0).tolist()
+    assert max(got.values()) > 2**31

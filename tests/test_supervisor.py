@@ -177,6 +177,25 @@ def test_validate_rejects_contract_violations():
         validate_spike_outputs(wrong_dtype, steps=8, batch=4, sizes=(5, 3))
 
 
+@pytest.mark.parametrize("corrupt,match", [
+    (lambda o: [o[0][:, :, 0], o[1]], "shape"),          # a 2-D train
+    (lambda o: [o[0][:, :3], o[1]], "shape"),             # wrong batch
+    (lambda o: [o[0], o[1][:, :, :2]], "shape"),          # wrong width
+    (lambda o: [o[0], _poke(o[1], -1.0)], "projection 1: non-binary"),
+    (lambda o: [_poke(o[0], -np.inf), o[1]], "projection 0: non-finite"),
+], ids=["2-D", "batch", "width", "negative", "minus-inf"])
+def test_validate_rejects_each_remaining_signature(corrupt, match):
+    with pytest.raises(OutputValidationError, match=match):
+        validate_spike_outputs(corrupt(good_outs()), steps=8, batch=4,
+                               sizes=(5, 3))
+
+
+def _poke(z, value):
+    z = z.copy()
+    z[3, 1, 2] = value
+    return z
+
+
 # -- supervised launch paths -------------------------------------------------
 
 def test_fault_free_launch_single_attempt_trims_replies():

@@ -67,26 +67,6 @@ def test_compile_work_halves_with_prejudging(pipeline):
     assert sw.host_bytes_peak < ideal.host_bytes_peak
 
 
-class TestLaunchers:
-    def test_train_launcher_with_failure_injection(self, tmp_path):
-        from repro.launch.train import main
-        out = main([
-            "--arch", "llama3.2-3b", "--smoke", "--steps", "30",
-            "--batch", "4", "--seq", "32", "--ckpt-dir", str(tmp_path),
-            "--ckpt-every", "10", "--simulate-failure", "15",
-            "--log-every", "100",
-        ])
-        assert out["last_loss"] < out["first_loss"]  # learning happened
-
-    def test_serve_launcher(self):
-        from repro.launch.serve import main
-        out = main([
-            "--arch", "qwen3-8b", "--smoke", "--batch", "2",
-            "--prompt-len", "8", "--gen", "4",
-        ])
-        assert out["tokens"].shape == (2, 4)
-
-
 class TestEntryPoints:
     """What every entry point does before its first jit, and the chip
     smoke's refusal to run anywhere but on a TPU."""
